@@ -13,11 +13,22 @@ Typical CPU-serving knobs (composed, not replaced — anything already in
                  intra_op_parallelism_threads=1"
     --xla-flags "--xla_force_host_platform_device_count=8"
     --env TF_CPP_MIN_LOG_LEVEL=3 --env REPRO_DECODE_BACKEND=batched
+
+:func:`apply` also places JAX's persistent compilation cache: where
+``JAX_COMPILATION_CACHE_DIR`` is set it is left alone, and otherwise the
+cache goes to ``.jax_cache/`` at the root of the checkout — a fixed path,
+since the path is part of what a later process must find again.  Every
+compiled program is kept, however quick its compile (the decode buckets
+each compile in about a second).
 """
 from __future__ import annotations
 
 import argparse
 import os
+
+#: compile cache used when JAX_COMPILATION_CACHE_DIR is not set
+CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), ".jax_cache")
 
 
 def add_args(ap: argparse.ArgumentParser) -> None:
@@ -38,8 +49,9 @@ def add_args(ap: argparse.ArgumentParser) -> None:
 
 
 def apply(args: argparse.Namespace) -> None:
-    """Install --env/--xla-flags into os.environ.  Must run before any
-    repro.core (hence jax) import to have any effect on XLA."""
+    """Install --env/--xla-flags and the compile-cache placement into
+    os.environ.  Must run before any repro.core (hence jax) import to have
+    any effect on XLA."""
     for spec in args.env:
         key, sep, val = spec.partition("=")
         if not sep or not key:
@@ -48,3 +60,5 @@ def apply(args: argparse.Namespace) -> None:
     if args.xla_flags:
         prev = os.environ.get("XLA_FLAGS", "")
         os.environ["XLA_FLAGS"] = f"{prev} {args.xla_flags}".strip()
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", CACHE_DIR)
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")

@@ -137,7 +137,8 @@ class DecodeConfig:
     """Decode-path knobs (see ``core/storage.py``).
 
     - ``backend`` — ``"numpy"`` (per-tile oracle loop) or ``"batched"``
-      (fused accelerator dispatches over the merged batch; bit-identical);
+      (fused accelerator dispatches over the merged batch; equal to the
+      numpy oracle within f32 rounding, see ``codec/batch.py``);
       ``None`` falls through to ``$REPRO_DECODE_BACKEND`` then ``"numpy"``.
     - ``roi`` — lower per-tile 8x8-block masks into plans so subframe scans
       decode only the blocks their boxes intersect (results bit-identical

@@ -257,7 +257,7 @@ class VideoStore:
         self.roi_decode = decode_cfg.roi
         # decode backend="numpy"|"batched": how TileStore.decode_tiles runs —
         # the per-tile numpy oracle loop, or fused accelerator dispatches
-        # over the whole merged batch (bit-identical; see codec/batch.py).
+        # over the whole merged batch (f32 tolerance; see codec/batch.py).
         self.decode_backend = decode_cfg.backend
         # tuning mode="background"|"inline"|"off": where policy-driven
         # retiling runs (async tuner thread / inside the scan / nowhere);
@@ -896,12 +896,32 @@ class VideoStore:
         return float(sum(e.store.storage_bytes()
                          for e in self._videos.values()))
 
+    def device(self) -> Optional[dict]:
+        """What the batched decode backend runs on, as JAX reports it:
+        platform, device kind, device count and device ids — plus
+        ``visible_chips``, the host chips libtpu was confined to
+        (``TPU_VISIBLE_CHIPS``; ``None`` when unconfined), since JAX numbers
+        a confined process's devices from 0 whichever chip it holds.
+        ``None`` under the numpy backend, which never starts a JAX
+        backend."""
+        if self.decode_backend != "batched":
+            return None
+        import jax
+
+        devs = jax.devices()
+        return {"platform": devs[0].platform,
+                "device_kind": devs[0].device_kind,
+                "count": len(devs),
+                "ids": [d.id for d in devs],
+                "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}
+
     def stats(self) -> dict:
         """JSON-able engine-wide accounting snapshot: catalog membership,
-        per-video decode/storage counters, and tile-cache stats.  This is
-        the ``stats`` RPC of the socket front end (``core/server.py``), and
-        what benchmarks use to assert cross-client cache sharing (a warm
-        repeat leaves ``tiles_decoded_total`` unchanged)."""
+        per-video decode/storage counters, tile-cache stats, and the decode
+        device (:meth:`device`).  This is the ``stats`` RPC of the socket
+        front end (``core/server.py``), and what benchmarks use to assert
+        cross-client cache sharing (a warm repeat leaves
+        ``tiles_decoded_total`` unchanged)."""
         with self.scheduler.lock:
             per_video = {
                 name: {"n_sots": len(e.store.sots),
@@ -935,7 +955,8 @@ class VideoStore:
                     "marshalling": {"marshal_s": marshal_s,
                                     "payload_bytes": payload_bytes,
                                     "by_transport": by_transport},
-                    "cache": dataclasses.asdict(self.tile_cache.stats())}
+                    "cache": dataclasses.asdict(self.tile_cache.stats()),
+                    "device": self.device()}
 
     # ------------------------------------------------------------- manifest
     def save(self, *, full: bool = False) -> None:

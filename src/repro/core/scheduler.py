@@ -120,6 +120,9 @@ class ScanScheduler:
         self._prefetch_cv = threading.Condition()
         self._prefetch_pending: set[GroupKey] = set()
         self._prefetch_inflight = 0
+        #: the last prefetch failure that was not a lost race, re-raised
+        #: (and cleared) by the next drain_prefetch()
+        self._prefetch_error: Optional[BaseException] = None
 
     # ----------------------------------------------------------- frontend
     def _normalize(self, plan) -> PhysicalPlan:
@@ -248,10 +251,15 @@ class ScanScheduler:
                                    prefetch=True)
             if rec.epoch != epoch:
                 self.cache.invalidate(video, sot_id, before_epoch=rec.epoch)
-        except Exception:
+        except (KeyError, OSError):
             # best-effort by contract: a lost race (drop_video, store-level
             # retile deleting files mid-read) abandons the prediction
             pass
+        except Exception as e:  # noqa: BLE001 - kept for drain_prefetch
+            # anything else (a decode or device failure) is a fault, not a
+            # lost race: record it for drain_prefetch() to re-raise
+            with self._prefetch_cv:
+                self._prefetch_error = e
         finally:
             with self._prefetch_cv:
                 self._prefetch_pending.discard(gkey)
@@ -261,7 +269,8 @@ class ScanScheduler:
     def drain_prefetch(self, timeout: Optional[float] = None) -> None:
         """Deterministic prefetch barrier: block until every prefetch job
         enqueued before this call has completed (tests and benchmarks use
-        it to make 'the next window is already resident' assertable)."""
+        it to make 'the next window is already resident' assertable).
+        Re-raises the last prefetch failure that was not a lost race."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._prefetch_cv:
             while self._prefetch_inflight:
@@ -272,6 +281,9 @@ class ScanScheduler:
                         f"drain_prefetch timed out with "
                         f"{self._prefetch_inflight} jobs in flight")
                 self._prefetch_cv.wait(remaining)
+            if self._prefetch_error is not None:
+                err, self._prefetch_error = self._prefetch_error, None
+                raise err
 
     def _execute_batch(self, pplans: list[PhysicalPlan]) -> list[ScanResult]:
         groups: dict[GroupKey, list[tuple[int, SOTScan]]] = {}

@@ -57,7 +57,7 @@ def tile_checksum(enc: dict) -> str:
 
 
 #: decode_tiles implementations: "numpy" = the per-tile oracle loop,
-#: "batched" = fused accelerator dispatches (bit-identical; codec/batch.py)
+#: "batched" = fused accelerator dispatches (f32 tolerance; codec/batch.py)
 DECODE_BACKENDS = ("numpy", "batched")
 
 
@@ -192,8 +192,9 @@ class TileStore:
 
         With ``decode_backend="batched"`` every (tile, GOP, mask) selection
         of the call is flattened into fused accelerator dispatches
-        (``codec/batch.py``) instead of the per-tile numpy loop; arrays and
-        the decode counters are bit-identical either way."""
+        (``codec/batch.py``) instead of the per-tile numpy loop.  The decode
+        counters are identical either way; the arrays agree to f32 rounding
+        (the tolerance ``codec/batch.py`` states)."""
         rec = self.sots[sot_id]
         span = rec.frame_end - rec.frame_start
         gop = self.encoder.gop

@@ -15,8 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.utils.jax_compat import shard_map
-
 
 def quantize_int8(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -72,7 +70,7 @@ def make_dp_compressed_grad_fn(loss_fn, mesh, *, axis_name: str = "data"):
         loss = jax.lax.pmean(loss, axis_name)
         return loss, grads, residuals
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis_name), P()),
         out_specs=(P(), P(), P()),

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.jax_compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -90,8 +88,9 @@ def ring_attention(q, k, v, *, mesh: Mesh, axis: str = "model",
         out = acc / l.transpose(0, 3, 1, 2)[..., None]
         return out.astype(q.dtype)
 
-    fn = shard_map(ring, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                   out_specs=q_spec, check_vma=False)
+    fn = jax.shard_map(ring, mesh=mesh,
+                       in_specs=(q_spec, kv_spec, kv_spec),
+                       out_specs=q_spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -15,7 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode.decode import BLK, decode_gop_blocks
+from repro.kernels.decode.decode import decode_gop_blocks
 from repro.kernels.decode.ref import decode_fused_ref
 
 #: floor for the padded column count — tiny batches share one trace
@@ -43,8 +43,7 @@ def use_pallas_default() -> bool:
 def _decode_fused(q: jnp.ndarray, *, qp: int, use_pallas: bool,
                   interpret: bool) -> jnp.ndarray:
     if use_pallas:
-        blk = min(BLK, q.shape[1])
-        return decode_gop_blocks(q, qp, interpret=interpret, blk=blk)
+        return decode_gop_blocks(q, qp, interpret=interpret)
     return decode_fused_ref(q, qp)
 
 
@@ -55,8 +54,9 @@ def decode_fused_op(q: jnp.ndarray, *, qp: int,
 
     Row 0 is dequantized with the intra matrix, rows 1+ with the inter
     matrix, each block IDCT'd, then summed cumulatively over F (the
-    closed-loop GOP reconstruction).  Bit-identical to the numpy
-    ``decode_tile`` arithmetic per column.
+    closed-loop GOP reconstruction).  Matches the numpy ``decode_tile``
+    arithmetic per column to f32 rounding (the contractions run at
+    ``HIGHEST`` precision; see ``repro/kernels/decode/ref.py``).
 
     M is padded to :func:`pad_bucket` columns (zero coefficients decode to
     zero pixels, sliced off before return), F is used as-is — callers

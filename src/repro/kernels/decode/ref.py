@@ -2,14 +2,15 @@
 
 This is not just the kernel oracle: on non-TPU backends it IS the batched
 decode implementation (one jitted XLA dispatch per size bucket).  Every op
-is chosen to be bit-identical to the numpy ``decode_tile`` arithmetic:
+follows the numpy ``decode_tile`` arithmetic, so the two agree to f32
+rounding accumulated over at most F frames:
 
-- dequant + the two 8x8 IDCT matmuls match ``np.einsum`` bitwise (same
-  two-GEMM contraction order);
-- the GOP reconstruction uses a *sequential* ``lax.scan`` prefix sum —
-  ``jnp.cumsum`` lowers to a log-depth parallel scan whose float
-  accumulation order differs from ``np.cumsum``, so it must not be used
-  here.
+- dequant + the two 8x8 IDCT matmuls use the same two-GEMM contraction
+  order as ``np.einsum``, at ``HIGHEST`` precision — on a TPU the default
+  f32 contraction rounds its inputs to bf16, far outside that agreement;
+- the GOP reconstruction uses a *sequential* ``lax.scan`` prefix sum, the
+  accumulation order of ``np.cumsum`` (``jnp.cumsum`` lowers to a
+  log-depth parallel scan).
 """
 from __future__ import annotations
 
@@ -33,8 +34,9 @@ def decode_fused_ref(q: jnp.ndarray, qp: int) -> jnp.ndarray:
         scale = jnp.concatenate(
             [mk[None], jnp.broadcast_to(mp, (n_frames - 1, 8, 8))], axis=0)
     c = (q.astype(jnp.float32) * scale[:, None]).reshape(-1, 8, 8)
-    x = jnp.einsum("ji,njk->nik", d, c)
-    x = jnp.einsum("nik,kl->nil", x, d).reshape(q.shape)
+    hi = jax.lax.Precision.HIGHEST
+    x = jnp.einsum("ji,njk->nik", d, c, precision=hi)
+    x = jnp.einsum("nik,kl->nil", x, d, precision=hi).reshape(q.shape)
     if n_frames == 1:
         return x
 

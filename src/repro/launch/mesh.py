@@ -12,19 +12,27 @@ Topology: a TPU v5e pod is modelled as a 16x16 = 256-chip 2D slice with
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules hand
+    specs to ``with_sharding_constraint``, which ``Explicit`` axes (the
+    ``make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, pods: int = 2):
     shape = (pods, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pods: int = 0):
     """Small mesh over host devices for tests (requires host-device flag)."""
     if pods:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return auto_mesh((pods, data, model), ("pod", "data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # Hardware constants for roofline terms (TPU v5e):

@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs.base import get_config, make_serve_config, reduce_config
 from repro.distributed import sharding as shd
 from repro.distributed.ctx import SERVE_RULES_1POD, use_sharding
+from repro.launch.mesh import auto_mesh
 from repro.models import zoo
 from repro.serve.serve_step import make_decode_step, make_prefill_step
 
@@ -43,7 +44,7 @@ def main() -> None:
     model_axis = 1
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(dims, ("data", "model")[: len(dims)])
+        mesh = auto_mesh(dims, ("data", "model")[: len(dims)])
         model_axis = mesh.shape.get("model", 1)
     cfg = make_serve_config(cfg, model_axis)
     cfg = dataclasses.replace(cfg, kv_cache_quant=args.kv_quant,

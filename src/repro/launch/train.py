@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs.base import get_config, reduce_config
 from repro.distributed import sharding as shd
 from repro.distributed.ctx import TRAIN_RULES_1POD, dp_rules, use_sharding
+from repro.launch.mesh import auto_mesh
 from repro.models import zoo
 from repro.train.checkpoint import CheckpointManager
 from repro.train.data import PrefetchPipeline, synthetic_token_batches
@@ -55,7 +56,7 @@ def main() -> None:
     mesh = None
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(dims, ("data", "model")[: len(dims)])
+        mesh = auto_mesh(dims, ("data", "model")[: len(dims)])
 
     params = zoo.init_model(cfg, jax.random.key(0))
     opt = init_opt_state(params)

@@ -33,7 +33,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.distributed.ctx import current_mesh, current_rules
 from repro.models.layers import dense_apply, init_dense, init_mlp, mlp_apply
-from repro.utils.jax_compat import shard_map
 
 
 def init_moe(key, cfg: ArchConfig) -> dict:
@@ -189,6 +188,6 @@ def _moe_tp_psum(p: dict, xt: jnp.ndarray, cfg: ArchConfig, mesh, model_axis: st
         return jax.lax.psum(out, model_axis)
 
     routed = {k: p[k] for k in ("router", "w_gate", "w_up", "w_down")}
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=tok_spec, check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=tok_spec, check_vma=False)
     return fn(routed, xt)

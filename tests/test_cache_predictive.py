@@ -485,6 +485,46 @@ class TestBitIdentityVsCacheOff:
             ctrl.close()
 
 
+class TestPrefetchFailures:
+    """A prefetch that loses a race (dropped video, files deleted by a
+    retile) is abandoned quietly; any other failure — a decode or device
+    error — is re-raised by the next ``drain_prefetch``."""
+
+    @pytest.mark.parametrize("exc,surfaces", [
+        (KeyError("tile dropped mid-read"), False),
+        (FileNotFoundError("tile file deleted by a retile"), False),
+        (RuntimeError("device failure"), True),
+    ])
+    def test_drain_prefetch(self, long_video, exc, surfaces):
+        frames, dets = long_video
+        store = VideoStore(cache=PREDICTIVE)
+        fill(store, "cam0", frames, dets, sot_len=32)
+        tiles = store.video("cam0").store
+        decode = tiles.decode_tiles
+
+        def failing_decode(sot_id, *a, **kw):
+            # the scanned windows (SOTs 0-2) decode; the SOTs the
+            # predictor fetches ahead of them fail
+            if sot_id >= 3:
+                raise exc
+            return decode(sot_id, *a, **kw)
+
+        try:
+            tiles.decode_tiles = failing_decode
+            for q in _windows(store, 3):
+                q.execute()
+            if surfaces:
+                with pytest.raises(type(exc), match=str(exc)):
+                    store.drain_prefetch(timeout=30)
+                # reported once: the next drain is clean
+                store.drain_prefetch(timeout=30)
+            else:
+                store.drain_prefetch(timeout=30)
+        finally:
+            del tiles.decode_tiles
+            store.close()
+
+
 # ========================================================== config surface
 class TestConfigSurface:
     def test_deprecated_kwargs_map_1to1(self):

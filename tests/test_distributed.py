@@ -32,12 +32,13 @@ def test_sharded_train_step_runs():
     from repro.configs.base import get_config, reduce_config
     from repro.distributed import sharding as shd
     from repro.distributed.ctx import TRAIN_RULES_1POD, use_sharding
+    from repro.launch.mesh import auto_mesh
     from repro.models import zoo
     from repro.train.optimizer import init_opt_state
     from repro.train.train_step import AdamWConfig, make_train_step
 
     cfg = reduce_config(get_config("olmo-1b"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     params = zoo.init_model(cfg, jax.random.key(0))
     p_shard = shd.param_shardings(params, cfg, mesh, mode="train")
     params = jax.device_put(params, p_shard)
@@ -150,11 +151,12 @@ def test_decode_step_sharded():
     from repro.configs.base import get_config, make_serve_config, reduce_config
     from repro.distributed import sharding as shd
     from repro.distributed.ctx import SERVE_RULES_1POD, use_sharding
+    from repro.launch.mesh import auto_mesh
     from repro.models import zoo
     from repro.serve.serve_step import make_decode_step
 
     cfg = reduce_config(get_config("qwen2-72b"))
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = auto_mesh((2, 2), ("data", "model"))
     scfg = make_serve_config(cfg, 2)
     params = zoo.init_model(scfg, jax.random.key(0))
     params = jax.device_put(params, shd.param_shardings(params, scfg, mesh,
