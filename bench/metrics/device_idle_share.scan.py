@@ -1,0 +1,6 @@
+"""Device: share of the traced window in which no op ran, in a scan cell."""
+from records import idle_pct
+
+
+def read(ctx):
+    return idle_pct(ctx)

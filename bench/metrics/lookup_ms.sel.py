@@ -1,0 +1,6 @@
+"""Planning: mean semantic-index lookup time of a selection."""
+from records import mean_ms
+
+
+def read(ctx):
+    return mean_ms(ctx, "sel", "lookup_s")

@@ -1,0 +1,6 @@
+"""Wire: mean seconds the server spent marshalling a selection reply."""
+from records import mean_ms
+
+
+def read(ctx):
+    return mean_ms(ctx, "sel", "marshal_s")

@@ -1,0 +1,7 @@
+"""Full-frame megapixels delivered to detector clients per second of the
+window."""
+from records import delivered_mpx_s
+
+
+def read(ctx):
+    return delivered_mpx_s(ctx, "scan")
