@@ -59,9 +59,9 @@ print(f"pixels decoded, full-tile {full_px / 1e6:.2f} M -> "
 #     --decode-backend on tasm_serve.py) flattens every (tile, GOP,
 #     block-mask) selection of a group fetch into one fused
 #     dequant+IDCT+cumsum dispatch — Pallas on TPU, jitted XLA elsewhere —
-#     instead of the per-tile numpy loop.  Results and decode counters are
-#     bit-identical; fine-tiled merged batches decode 1.5-5x faster (see
-#     BENCH_decode_kernel.json)
+#     instead of the per-tile numpy loop.  Decode counters are identical
+#     and pixels agree within codec.batch.ORACLE_ATOL; bench/run.py
+#     measures its speed on the chip (PERF.md)
 from repro.core import DecodeConfig
 
 batched = VideoStore(decode=DecodeConfig(backend="batched"))

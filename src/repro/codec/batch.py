@@ -32,9 +32,11 @@ flattened into a padded block stream:
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.kernels.decode.ops import MIN_COLUMNS, decode_fused_op, pad_bucket
+from repro.utils import trace
 
 #: max abs difference from the numpy oracle on 0-255 pixels (module doc)
 ORACLE_ATOL = 1e-2
@@ -64,6 +66,47 @@ class _Slot:
         self.bsel = bsel            # None = full tile
         self.offset = offset
         self.span = span
+
+
+def _gather(slots: list[_Slot], f_bucket: int, m_pad: int) -> np.ndarray:
+    """One group's ``[F, M, 8, 8]`` int16 block stream: each slot's
+    selected GOPs, ROI blocks only, in its column span."""
+    q = np.zeros((f_bucket, m_pad, 8, 8), dtype=np.int16)
+    for s in slots:
+        _, enc, idx = s.item
+        kq = _gather_gops(enc["kq"], idx)          # [G, nb, 8, 8]
+        if s.bsel is not None:
+            kq = kq[:, s.bsel]
+        q[0, s.offset:s.offset + s.span] = kq.reshape(-1, 8, 8)
+        if s.n > 1:
+            pq = _gather_gops(enc["pq"], idx)[:, :s.n - 1]
+            if s.bsel is not None:
+                pq = pq[:, :, s.bsel]
+            # [G, n-1, nb, 8, 8] -> [n-1, G*nb, 8, 8] gop-major columns
+            q[1:s.n, s.offset:s.offset + s.span] = \
+                pq.transpose(1, 0, 2, 3, 4).reshape(s.n - 1, s.span, 8, 8)
+    return q
+
+
+def _scatter(s: _Slot, out: np.ndarray) -> np.ndarray:
+    """One slot's columns of the decoded stream as its output canvas."""
+    _, enc, _ = s.item
+    h, w = enc["h"], enc["w"]
+    seg = out[:s.n, s.offset:s.offset + s.span]
+    if s.bsel is None:
+        # [n, G, h/8, w/8, 8, 8] -> gop-major frames [G*n, h, w]
+        arr = seg.reshape(s.n, s.n_gops, h // 8, w // 8, 8, 8)
+        arr = arr.transpose(1, 0, 2, 4, 3, 5)
+        return np.ascontiguousarray(arr.reshape(s.n_gops * s.n, h, w))
+    canvas = np.zeros((s.n_gops * s.n, h, w), dtype=np.float32)
+    view = canvas.reshape(-1, h // 8, 8, w // 8, 8)
+    rs, cs = np.divmod(s.bsel, w // 8)
+    frames = seg.reshape(s.n, s.n_gops, -1, 8, 8)
+    frames = frames.transpose(1, 0, 2, 3, 4).reshape(
+        s.n_gops * s.n, -1, 8, 8)
+    # same advanced-index write as the oracle's ROI scatter
+    view[:, rs, :, cs] = frames.transpose(1, 0, 2, 3)
+    return canvas
 
 
 def decode_tile_batch(items, *, use_pallas: bool | None = None,
@@ -105,41 +148,17 @@ def decode_tile_batch(items, *, use_pallas: bool | None = None,
 
     for (qp, f_bucket), slots in slots_by_group.items():
         total = columns[(qp, f_bucket)]
-        m_pad = pad_bucket(total, lo=MIN_COLUMNS)
-        q = np.zeros((f_bucket, m_pad, 8, 8), dtype=np.int16)
-        for s in slots:
-            _, enc, idx = s.item
-            kq = _gather_gops(enc["kq"], idx)          # [G, nb, 8, 8]
-            if s.bsel is not None:
-                kq = kq[:, s.bsel]
-            q[0, s.offset:s.offset + s.span] = kq.reshape(-1, 8, 8)
-            if s.n > 1:
-                pq = _gather_gops(enc["pq"], idx)[:, :s.n - 1]
-                if s.bsel is not None:
-                    pq = pq[:, :, s.bsel]
-                # [G, n-1, nb, 8, 8] -> [n-1, G*nb, 8, 8] gop-major columns
-                q[1:s.n, s.offset:s.offset + s.span] = \
-                    pq.transpose(1, 0, 2, 3, 4).reshape(s.n - 1, s.span, 8, 8)
-        out = np.asarray(decode_fused_op(q, qp=qp, use_pallas=use_pallas,
-                                         interpret=interpret))
-        for s in slots:
-            i, enc, _ = s.item
-            h, w = enc["h"], enc["w"]
-            seg = out[:s.n, s.offset:s.offset + s.span]
-            if s.bsel is None:
-                # [n, G, h/8, w/8, 8, 8] -> gop-major frames [G*n, h, w]
-                arr = seg.reshape(s.n, s.n_gops, h // 8, w // 8, 8, 8)
-                arr = arr.transpose(1, 0, 2, 4, 3, 5)
-                results[i] = np.ascontiguousarray(
-                    arr.reshape(s.n_gops * s.n, h, w))
-            else:
-                canvas = np.zeros((s.n_gops * s.n, h, w), dtype=np.float32)
-                view = canvas.reshape(-1, h // 8, 8, w // 8, 8)
-                rs, cs = np.divmod(s.bsel, w // 8)
-                frames = seg.reshape(s.n, s.n_gops, -1, 8, 8)
-                frames = frames.transpose(1, 0, 2, 3, 4).reshape(
-                    s.n_gops * s.n, -1, 8, 8)
-                # same advanced-index write as the oracle's ROI scatter
-                view[:, rs, :, cs] = frames.transpose(1, 0, 2, 3)
-                results[i] = canvas
+        with trace.span("tasm.decode.gather"):
+            q = _gather(slots, f_bucket, pad_bucket(total, lo=MIN_COLUMNS))
+        with trace.span("tasm.decode.dispatch"):
+            out = decode_fused_op(q, qp=qp, use_pallas=use_pallas,
+                                  interpret=interpret)
+        with trace.span("tasm.decode.device"):
+            out = jax.block_until_ready(out)
+        with trace.span("tasm.decode.d2h"):
+            out = np.asarray(out)
+        with trace.span("tasm.decode.scatter"):
+            for s in slots:
+                i, _, _ = s.item
+                results[i] = _scatter(s, out)
     return results

@@ -78,7 +78,6 @@ import json
 import os
 import pathlib
 import shutil
-import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
@@ -98,6 +97,7 @@ from repro.core.semantic_index import SemanticIndex
 from repro.core.storage import SOTRecord, TileStore, tile_checksum
 from repro.core.tile_cache import CacheStats, TileCache
 from repro.core.tuner import PhysicalTuner, TunerStats
+from repro.utils import trace
 
 #: valid what-if cost granularities: "tile" = standard full-tile decoder
 #: (the basis for layout decisions), "block" = actual ROI-restricted decode
@@ -518,9 +518,10 @@ class VideoStore:
             else:
                 cnf = plan.cnf
             flat_labels = tuple(sorted({l for clause in cnf for l in clause}))
-            t0 = time.perf_counter()
-            boxes_by_frame = entry.index.query(name, cnf, plan.frame_range)
-            pplan.lookup_s += time.perf_counter() - t0
+            with trace.span("tasm.plan", profile=False, video=name) as sp:
+                boxes_by_frame = entry.index.query(name, cnf,
+                                                   plan.frame_range)
+            pplan.lookup_s += sp.seconds
             if remaining is not None:
                 boxes_by_frame = _apply_limit(boxes_by_frame, remaining)
                 remaining -= sum(len(b) for b in boxes_by_frame.values())
@@ -921,7 +922,19 @@ class VideoStore:
         device (:meth:`device`).  This is the ``stats`` RPC of the socket
         front end (``core/server.py``), and what benchmarks use to assert
         cross-client cache sharing (a warm repeat leaves
-        ``tiles_decoded_total`` unchanged)."""
+        ``tiles_decoded_total`` unchanged).
+
+        ``spans`` is :func:`repro.utils.trace.summary` of the process: for
+        each span or counter of the served path, its count, total and
+        maximum over the records still held (the last 65536).  Span values
+        are seconds: ``tasm.queue`` (a request's wait in the serving
+        queue), ``tasm.plan`` (index lookup), ``tasm.fetch.batch`` (a
+        batch's fetch phase), ``tasm.fetch`` (one group fetch) and its
+        steps ``tasm.cache.get``, ``tasm.store.read``,
+        ``tasm.decode.gather`` / ``.dispatch`` / ``.device`` / ``.d2h`` /
+        ``.scatter`` (per dispatch group) and ``tasm.cache.put``, then
+        ``tasm.crop`` (per plan) and ``tasm.marshal`` (per reply); the
+        counter ``tasm.batch_plans`` is plans per served batch."""
         with self.scheduler.lock:
             per_video = {
                 name: {"n_sots": len(e.store.sots),
@@ -956,7 +969,8 @@ class VideoStore:
                                     "payload_bytes": payload_bytes,
                                     "by_transport": by_transport},
                     "cache": dataclasses.asdict(self.tile_cache.stats()),
-                    "device": self.device()}
+                    "device": self.device(),
+                    "spans": trace.summary()}
 
     # ------------------------------------------------------------- manifest
     def save(self, *, full: bool = False) -> None:

@@ -42,6 +42,7 @@ callers merge without any coordination on their part.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -55,6 +56,7 @@ from repro.core.layout import BBox, TileLayout, block_coverage
 from repro.core.query import (PhysicalPlan, ScanPlan, ScanQuery, ScanResult,
                               ScanStats, SOTScan)
 from repro.core.tile_cache import TileCache, WorkloadPredictor
+from repro.utils import trace
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.engine import VideoStore
@@ -297,16 +299,18 @@ class ScanScheduler:
         batch_decode_s = 0.0
         if groups:
             keys = sorted(groups)
-            t0 = time.perf_counter()
-            if len(keys) == 1:
-                k = keys[0]
-                fetched[k] = self._fetch(k, [ss for _, ss in groups[k]])
-            else:
-                pool = self._ensure_pool()
-                fn = lambda k: self._fetch(k, [ss for _, ss in groups[k]])
-                for k, f in zip(keys, pool.map(fn, keys)):
-                    fetched[k] = f
-            batch_decode_s = time.perf_counter() - t0
+            with trace.span("tasm.fetch.batch", profile=False,
+                            groups=len(keys)) as sp:
+                if len(keys) == 1:
+                    k = keys[0]
+                    fetched[k] = self._fetch(k, [ss for _, ss in groups[k]])
+                else:
+                    pool = self._ensure_pool()
+                    fn = lambda k: self._fetch(k,
+                                               [ss for _, ss in groups[k]])
+                    for k, f in zip(keys, pool.map(fn, keys)):
+                        fetched[k] = f
+            batch_decode_s = sp.seconds
 
         results = [self._finish_one(i, pp, groups, fetched, batch_decode_s,
                                     single_plan=len(pplans) == 1)
@@ -321,8 +325,17 @@ class ScanScheduler:
         members, so a shared tile decodes each needed block at most once;
         a cached entry covering a member's mask (full tile, or a superset
         ROI) is a hit, and a covering miss re-decodes the union of the old
-        entry's mask and the new need (never shrinking coverage)."""
-        t0 = time.perf_counter()
+        entry's mask and the new need (never shrinking coverage).  Timed
+        as ``tasm.fetch``, in memory only: the steps inside are the
+        profiled spans."""
+        with trace.span("tasm.fetch", profile=False, video=gkey[0],
+                        sot=gkey[1]) as sp:
+            f = self._fetch_group(gkey, members)
+        f.seconds = sp.seconds
+        return f
+
+    def _fetch_group(self, gkey: GroupKey,
+                     members: list[SOTScan]) -> _GroupFetch:
         video, sot_id = gkey
         entry = self.engine.video(video)
         rec = entry.store.sots[sot_id]
@@ -360,23 +373,27 @@ class ScanScheduler:
         out: dict[int, np.ndarray] = {}
         to_decode: dict[int, object] = {}        # tile -> mask
         decode_depth: dict[int, int] = {}        # tile -> decode depth
-        for t in sorted(depth):
-            key = (video, sot_id, epoch, t)
-            arr = self.cache.get(key, depth[t], blocks=masks[t])
-            if arr is not None:
-                out[t] = arr
-                continue
-            nf, m = depth[t], masks[t]
-            cov = self.cache.coverage(key)
-            if cov is not None:
-                # widen to cover the existing entry too, so the re-decode
-                # can replace it (put never shrinks depth or coverage)
-                nf = max(nf, cov[0])
-                m = None if (m is None or cov[1] is None) else m | cov[1]
-                if m is not None and len(m) == rec.layout.tile_blocks(t):
-                    m = None
-            to_decode[t] = m
-            decode_depth[t] = nf
+        with trace.span("tasm.cache.get"):
+            for t in sorted(depth):
+                key = (video, sot_id, epoch, t)
+                arr = self.cache.get(key, depth[t], blocks=masks[t])
+                if arr is not None:
+                    out[t] = arr
+                    continue
+                nf, m = depth[t], masks[t]
+                cov = self.cache.coverage(key)
+                if cov is not None:
+                    # widen to cover the existing entry too, so the
+                    # re-decode can replace it (put never shrinks depth or
+                    # coverage)
+                    nf = max(nf, cov[0])
+                    m = None if (m is None or cov[1] is None) \
+                        else m | cov[1]
+                    if m is not None and \
+                            len(m) == rec.layout.tile_blocks(t):
+                        m = None
+                to_decode[t] = m
+                decode_depth[t] = nf
         fresh: set[int] = set()
         pixels_by_tile: dict[int, float] = {}
         if to_decode:
@@ -388,17 +405,18 @@ class ScanScheduler:
             dec = entry.store.decode_tiles(sot_id, sorted(to_decode),
                                            n_frames=decode_depth,
                                            blocks=blocks)
-            for t, arr in dec.items():
-                out[t] = arr
-                fresh.add(t)
-                m = blocks[t]
-                n_blocks = rec.layout.tile_blocks(t) if m is None else len(m)
-                pixels_by_tile[t] = float(n_blocks * 64 * arr.shape[0])
-                self.cache.put((video, sot_id, epoch, t), arr, blocks=m)
+            with trace.span("tasm.cache.put"):
+                for t, arr in dec.items():
+                    out[t] = arr
+                    fresh.add(t)
+                    m = blocks[t]
+                    n_blocks = rec.layout.tile_blocks(t) if m is None \
+                        else len(m)
+                    pixels_by_tile[t] = float(n_blocks * 64 * arr.shape[0])
+                    self.cache.put((video, sot_id, epoch, t), arr, blocks=m)
         return _GroupFetch(epoch=epoch, layout=rec.layout,
                            tiles=out, fresh=fresh, need=need,
-                           pixels_by_tile=pixels_by_tile,
-                           seconds=time.perf_counter() - t0)
+                           pixels_by_tile=pixels_by_tile)
 
     # ----------------------------------------------------------- per plan
     def _finish_one(self, idx: int, pplan: PhysicalPlan,
@@ -421,6 +439,7 @@ class ScanScheduler:
             if single_plan:
                 # old executor semantics: wall time of the decode phase
                 stats.decode_s = batch_decode_s
+            crops = []          # (SOTScan, its SOT's record, its fetch)
             for ss in pplan.sot_scans:
                 gkey = (ss.video, ss.sot_id)
                 rec = engine.video(ss.video).store.sots[ss.sot_id]
@@ -448,12 +467,15 @@ class ScanScheduler:
                         stats.pixels_decoded += f.pixels_by_tile.get(t, 0.0)
                     else:
                         stats.cache_hits += 1
-                out = regions_by_video[ss.video]
-                for frame, boxes in sorted(ss.boxes_by_frame.items()):
-                    rel = frame - rec.frame_start
-                    for box in boxes:
-                        out.append((frame, box,
-                                    _crop(f.layout, f.tiles, rel, box)))
+                crops.append((ss, rec, f))
+            with trace.span("tasm.crop"):
+                for ss, rec, f in crops:
+                    out = regions_by_video[ss.video]
+                    for frame, boxes in sorted(ss.boxes_by_frame.items()):
+                        rel = frame - rec.frame_start
+                        for box in boxes:
+                            out.append((frame, box,
+                                        _crop(f.layout, f.tiles, rel, box)))
 
         # policy hooks, serially per SOT, dispatched through the tuner:
         # inline mode observes + retiles here (charged to this query's
@@ -496,12 +518,18 @@ class ServingSession:
     ``submit`` accepts a :class:`ScanQuery`, :class:`ScanPlan` or
     :class:`PhysicalPlan` and returns a :class:`concurrent.futures.Future`
     resolving to the :class:`ScanResult`.
+
+    Each submission's wait in the queue is recorded as ``tasm.queue``
+    (ids ``req`` and ``batch``), and the number of plans each batch hands
+    to ``execute_many`` as the counter ``tasm.batch_plans``.
     """
 
     def __init__(self, scheduler: ScanScheduler, *, max_batch: int = 64):
         self._scheduler = scheduler
         self._max_batch = max(1, int(max_batch))
         self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._req_ids = itertools.count()
+        self._batch_ids = itertools.count()
         self._closed = False
         # orders submit's check+enqueue against close's flag-set, so a
         # submission either lands ahead of the _STOP sentinel or raises
@@ -515,7 +543,7 @@ class ServingSession:
         with self._state_lock:
             if self._closed:
                 raise RuntimeError("serving session is closed")
-            self._q.put((plan, fut))
+            self._q.put((plan, fut, next(self._req_ids), time.monotonic()))
         return fut
 
     def execute(self, plan) -> ScanResult:
@@ -538,9 +566,11 @@ class ServingSession:
                     stop = True
                     break
                 batch.append(nxt)
+            started, bid = time.monotonic(), next(self._batch_ids)
             # normalize per submission so one bad query can't fail the batch
             plans, live = [], []
-            for plan, fut in batch:
+            for plan, fut, rid, queued in batch:
+                trace.record("tasm.queue", queued, started, req=rid, batch=bid)
                 if not fut.set_running_or_notify_cancel():
                     continue  # caller cancelled while queued
                 try:
@@ -549,6 +579,7 @@ class ServingSession:
                 except BaseException as e:
                     fut.set_exception(e)
             if plans:
+                trace.count("tasm.batch_plans", len(plans), batch=bid)
                 try:
                     results = self._scheduler.execute_many(plans)
                 except BaseException as e:

@@ -50,7 +50,6 @@ import pathlib
 import queue
 import socket
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
@@ -63,6 +62,7 @@ from repro.core.policies import policy_from_spec
 from repro.core.query import ScanPlan
 from repro.core.shm import (SegmentPool, resolve_transport, shm_available,
                             DEFAULT_POOL_BYTES)
+from repro.utils import trace
 
 
 def _cost_model_from_doc(doc: Optional[dict]) -> Optional[CostModel]:
@@ -425,51 +425,53 @@ class VideoStoreServer:
               stats: Optional[list] = None) -> None:
         """Encode and enqueue one reply.  ``stats`` — the reply's live
         ScanStats objects — turns on marshalling accounting and makes the
-        reply eligible for the shared-memory transport."""
-        t0 = time.perf_counter()
-        leased: list = []
-        on_payload = None
-        if stats:
-            def on_payload(clean, transport, nbytes):
-                self._stamp_marshalling(clean, stats, transport, nbytes,
-                                        time.perf_counter() - t0)
-        try:
-            payload = wire.dumps(
-                doc, codec=self.codec, max_bytes=self.max_frame_bytes,
-                segment_writer=self._segment_writer(st, leased)
-                if stats else None,
-                on_payload=on_payload)
-        except wire.WireError as e:
-            # the RESPONSE broke the frame limit (e.g. a scan returned more
-            # region bytes than max_frame_bytes): tell the client instead
-            # of silently dropping the connection
-            self._release_leases(st, leased)
-            leased = []
-            payload = wire.dumps(wire.error_doc(doc.get("id"), e),
-                                 codec=self.codec,
-                                 max_bytes=self.max_frame_bytes)
-        delivered = False
-        try:
-            st.outq.put_nowait(payload)
-            delivered = True
-        except queue.Full:
-            # slow consumer: hundreds of unread responses queued — cut it
-            # loose rather than buffer unboundedly (its writer thread may
-            # be stuck in sendall; shutdown() unsticks that too)
+        reply eligible for the shared-memory transport.  Timed as
+        ``tasm.marshal``; ``marshal_s`` is its time up to the payload's
+        packing."""
+        with trace.span("tasm.marshal") as sp:
+            leased: list = []
+            on_payload = None
+            if stats:
+                def on_payload(clean, transport, nbytes):
+                    self._stamp_marshalling(clean, stats, transport, nbytes,
+                                            sp.seconds)
             try:
-                st.sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
+                payload = wire.dumps(
+                    doc, codec=self.codec, max_bytes=self.max_frame_bytes,
+                    segment_writer=self._segment_writer(st, leased)
+                    if stats else None,
+                    on_payload=on_payload)
+            except wire.WireError as e:
+                # the RESPONSE broke the frame limit (e.g. a scan returned
+                # more region bytes than max_frame_bytes): tell the client
+                # instead of silently dropping the connection
+                self._release_leases(st, leased)
+                leased = []
+                payload = wire.dumps(wire.error_doc(doc.get("id"), e),
+                                     codec=self.codec,
+                                     max_bytes=self.max_frame_bytes)
+            delivered = False
             try:
-                st.sock.close()
-            except OSError:
-                pass
-        # leases racing connection teardown: _serve_conn sets st.closed
-        # BEFORE release_owner, we re-check closed AFTER leasing — one of
-        # the two sides is guaranteed to observe the other's write, so a
-        # segment can't slip past both and leak
-        if leased and (not delivered or st.closed):
-            self._release_leases(st, leased)
+                st.outq.put_nowait(payload)
+                delivered = True
+            except queue.Full:
+                # slow consumer: hundreds of unread responses queued — cut
+                # it loose rather than buffer unboundedly (its writer thread
+                # may be stuck in sendall; shutdown() unsticks that too)
+                try:
+                    st.sock.shutdown(socket.SHUT_RDWR)
+                except OSError:
+                    pass
+                try:
+                    st.sock.close()
+                except OSError:
+                    pass
+            # leases racing connection teardown: _serve_conn sets
+            # st.closed BEFORE release_owner, we re-check closed AFTER
+            # leasing — one of the two sides is guaranteed to observe the
+            # other's write, so a segment can't slip past both and leak
+            if leased and (not delivered or st.closed):
+                self._release_leases(st, leased)
 
     def _release_leases(self, st: _ConnState, names: list) -> None:
         if names and self._shm_pool is not None:

@@ -24,6 +24,7 @@ import numpy as np
 from repro.codec.encode import EncoderConfig, decode_tile, encode_tile
 from repro.core.btree import BPlusTree
 from repro.core.layout import TileLayout, single_tile_layout
+from repro.utils import trace
 
 
 @dataclass
@@ -207,17 +208,18 @@ class TileStore:
         out = {}
         pixels = 0
         plan = []   # (tile, enc, n_full, tail, mask)
-        for t in tile_idxs:
-            nf = depth[t]
-            n_full = nf // gop
-            tail = nf - n_full * gop
-            n_gops = n_full + (1 if tail else 0)
-            enc = self._read_tile(rec, t, n_gops=n_gops)
-            mask = (blocks or {}).get(t)
-            n_blocks = (enc["h"] // 8) * (enc["w"] // 8) if mask is None \
-                else len(mask)
-            pixels += n_blocks * 64 * nf
-            plan.append((t, enc, n_full, tail, mask))
+        with trace.span("tasm.store.read"):
+            for t in tile_idxs:
+                nf = depth[t]
+                n_full = nf // gop
+                tail = nf - n_full * gop
+                n_gops = n_full + (1 if tail else 0)
+                enc = self._read_tile(rec, t, n_gops=n_gops)
+                mask = (blocks or {}).get(t)
+                n_blocks = (enc["h"] // 8) * (enc["w"] // 8) \
+                    if mask is None else len(mask)
+                pixels += n_blocks * 64 * nf
+                plan.append((t, enc, n_full, tail, mask))
         if self.decode_backend == "batched":
             # jax rides in only when the batched backend is actually used
             from repro.codec.batch import decode_tile_batch

@@ -4,4 +4,3 @@ from repro.utils.tree import (
     tree_map_with_name,
     flatten_names,
 )
-from repro.utils.timing import Timer, time_call

@@ -21,6 +21,7 @@ from repro.core import (NoTilingPolicy, RemoteError, RemoteVideoStore,
                         VideoStore, VideoStoreServer, uniform_layout)
 from repro.core import wire
 from repro.core.cost import CostModel
+from repro.utils import trace
 
 ENC = EncoderConfig(gop=16, qp=8)
 MODEL = CostModel(beta=1.4e-8, gamma=1e-5)
@@ -88,10 +89,18 @@ class TestRemoteScans:
                         s.scan("cam0").labels("person").frames(0, 16),
                         s.scan("cam0").labels("car").frames(16, 32)]
         ref = [q.execute() for q in mk(store)]
+        t0 = time.monotonic()
         got = client.execute_many(mk(client))
+        recs = trace.window(t0, time.monotonic())
         assert len(got) == 3
         for r, g in zip(ref, got):
             assert_regions_equal(r.regions, g.regions)
+        # one reply carries the three results: each is stamped with an
+        # even share of the reply's tasm.marshal span, up to its packing
+        shares = {g.stats.marshal_s for g in got}
+        assert len(shares) == 1 and shares.pop() > 0
+        assert any(r.value >= sum(g.stats.marshal_s for g in got)
+                   for r in recs if r.name == "tasm.marshal")
 
     def test_limit_and_estimation_only(self, served):
         store, _, client, _ = served
@@ -206,6 +215,12 @@ class TestRemoteMutations:
         assert doc["tiles_decoded_total"] == \
             store.video("cam0").store.tiles_decoded_total
         assert doc["cache"]["entries"] >= 1
+        # the operator's span summary: the served scan queued, fetched and
+        # cropped before its reply went out
+        for name in ("tasm.queue", "tasm.fetch", "tasm.crop"):
+            s = doc["spans"][name]
+            assert s["count"] >= 1 and 0 <= s["max"] <= s["total"]
+        assert doc["spans"]["tasm.batch_plans"]["max"] >= 1
 
 
 # ------------------------------------------------------------ error paths

@@ -1,14 +1,17 @@
 """Serving layer: epoch-keyed tile cache, merging scan scheduler, concurrent
 sessions, and the scans-racing-a-retile invariants."""
 import threading
+import time
 
 import numpy as np
 import pytest
 
 from repro.codec.encode import EncoderConfig
-from repro.core import (NoTilingPolicy, RegretPolicy, TileCache, VideoStore,
+from repro.core import (DecodeConfig, NoTilingPolicy, RegretPolicy,
+                        TileCache, VideoStore,
                         uniform_layout)
 from repro.core.cost import CostModel
+from repro.utils import trace
 
 ENC = EncoderConfig(gop=16, qp=8)
 MODEL = CostModel(beta=1.4e-8, gamma=1e-5)
@@ -21,6 +24,11 @@ def fill(store, name, frames, dets, policy=None):
                     cost_model=MODEL)
     store.ingest(name, frames)
     store.add_detections(name, {f: d for f, d in enumerate(dets)})
+
+
+def union_sots(results):
+    return {(ss.video, ss.sot_id) for r in results
+            for ss in r.plan.sot_scans}
 
 
 def assert_regions_equal(a, b):
@@ -154,8 +162,10 @@ class TestExecuteMany:
         batch = VideoStore()
         fill(batch, "cam0", frames, dets)
         base = batch.video("cam0").store.tiles_decoded_total
+        t0 = time.monotonic()
         batch_res = batch.execute_many(
             [batch.scan("cam0").labels(l).frames(*fr) for l, fr in queries])
+        recs = trace.window(t0, time.monotonic())
 
         # each shared (sot, tile) decoded exactly once: the batch decodes
         # the union of needed tiles, strictly less than the serial sum
@@ -174,6 +184,16 @@ class TestExecuteMany:
         for r in batch_res:
             needed = sum(len(ss.tile_idxs) for ss in r.plan.sot_scans)
             assert r.stats.tiles_fetched == needed
+        # each group fetch's seconds (its tasm.fetch span) go to its first
+        # consumer: the first query needs every group, the rest get none
+        fetch_s = [r.value for r in recs if r.name == "tasm.fetch"]
+        assert len(fetch_s) == len(union_sots(batch_res))
+        assert batch_res[0].stats.decode_s == pytest.approx(sum(fetch_s),
+                                                            rel=1e-12)
+        assert [r.stats.decode_s for r in batch_res[1:]] == [0.0] * 3
+        # lookup_s is each plan's tasm.plan span
+        assert sorted(r.value for r in recs if r.name == "tasm.plan") == \
+            sorted(r.stats.lookup_s for r in batch_res)
 
     def test_batch_with_retiling_policy_matches_serial(self, small_video):
         frames, dets = small_video
@@ -333,6 +353,46 @@ class TestServingSession:
         union = {(ss.sot_id, t) for ss in results[0].plan.sot_scans
                  for t in ss.tile_idxs}
         assert sum(r.stats.cache_misses for r in results) == len(union)
+
+    def test_served_batch_records_queue_occupancy_and_decode_steps(
+            self, small_video):
+        frames, dets = small_video
+        store = VideoStore(decode=DecodeConfig(backend="batched"))
+        fill(store, "cam0", frames, dets)
+        with store.serve() as session:
+            with store.scheduler.lock:
+                # the dispatcher takes this scan alone and blocks on the
+                # lock, while two selections queue up behind it
+                first = session.submit(
+                    store.scan("cam0").labels("car").decode(False))
+                deadline = time.monotonic() + 30
+                while not session._q.empty() and \
+                        time.monotonic() < deadline:
+                    time.sleep(0.005)
+                time.sleep(0.05)
+                t0 = time.monotonic()
+                futs = [session.submit(
+                    store.scan("cam0").labels(label).frames(0, 32))
+                    for label in ("car", "person")]
+            results = [f.result(timeout=60) for f in futs]
+            first = first.result(timeout=60)
+        recs = trace.window(t0, time.monotonic())
+        queued = [r for r in recs if r.name == "tasm.queue"]
+        assert len(queued) == 2 and all(r.value >= 0 for r in queued)
+        (bid,) = {r.ids["batch"] for r in queued}
+        assert [r.value for r in recs if r.name == "tasm.batch_plans"
+                and r.ids["batch"] == bid] == [2]
+        # one decode per merged group, each with one record of every step
+        fetch_s = [r.value for r in recs if r.name == "tasm.fetch"]
+        assert len(fetch_s) == len(union_sots(results)) >= 1
+        for step in ("gather", "dispatch", "device", "d2h", "scatter"):
+            assert sum(r.name == f"tasm.decode.{step}" for r in recs) == \
+                len(fetch_s), step
+        # first-consumer rule: the merged groups' seconds are charged once
+        assert sum(r.stats.decode_s for r in results) == \
+            pytest.approx(sum(fetch_s), rel=1e-12)
+        assert sorted(r.value for r in recs if r.name == "tasm.plan") == \
+            sorted(r.stats.lookup_s for r in results + [first])
 
     def test_bad_query_fails_only_its_future(self, small_video):
         frames, dets = small_video
