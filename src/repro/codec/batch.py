@@ -11,31 +11,48 @@ GOP; a reduced-precision (bf16) contraction misses by orders of magnitude
 more.  Within one backend the arithmetic is deterministic, so cache on vs
 off and serial vs merged batches stay bit-identical.  Instead of one
 einsum call per tile per GOP inside a Python loop, the whole batch is
-flattened into a padded block stream:
+flattened into a padded block stream of ``[F, M, 8, 8]`` int16 columns
+(row 0 the intra keyframe, rows 1..n-1 the inter residuals), one column a
+selected 8x8 block of one GOP:
 
-1. **Gather** — for every item, the selected GOPs' coefficient blocks are
-   gathered (ROI block masks applied *here*, on the host, so masked-out
-   blocks never reach the accelerator) into columns of a ``[F, M, 8, 8]``
-   int16 stream: row 0 the intra keyframe, rows 1..n-1 the inter residuals.
-2. **Bucket** — items are grouped by ``(qp, F bucket)``; each group's
-   stream is allocated at power-of-two column counts
+1. **Bucket** — items are grouped by ``(qp, F bucket)``; each group's
+   stream has a power-of-two column count
    (:func:`repro.kernels.decode.ops.pad_bucket`) so jit traces stay bounded
    across arbitrary tile layouts.  Frame-depth padding appends zero
    coefficient rows, which decode to zero pixels *after* every real frame
-   and are sliced off.
-3. **Dispatch** — one fused dequant+IDCT+cumsum call per group: the Pallas
-   kernel on TPU, the jitted jnp path under XLA elsewhere (both within
-   :data:`ORACLE_ATOL` of numpy — see ``repro/kernels/decode``).
-4. **Scatter** — each item's columns are scattered back into its output
-   canvas exactly like the oracle (full tiles via the block-grid reshape,
-   ROI masks via the same advanced-index write, unselected blocks zero).
+   and are dropped.
+2. **Gather** — each selected GOP's stored rows (its keyframe blocks and
+   each residual frame's blocks) are copied once, by slices, into their
+   place in the stream; ROI block masks are applied *here*, by ``np.take``
+   into the stream, so masked-out blocks never reach the accelerator.
+   Only the padding is zeroed.
+3. **Dispatch** — one program per group runs the fused
+   dequant+IDCT+cumsum (the Pallas kernel on TPU, the jitted jnp path
+   under XLA elsewhere; both within :data:`ORACLE_ATOL` of numpy — see
+   ``repro/kernels/decode``) and copies the frames back in canvas order,
+   steered by a table of one word a column (``repro/kernels/decode/ops``).
+4. **Scatter** — a full tile's canvas of one GOP (a mask covering every
+   block counts as full) is a view of the copied-back planes; more GOPs
+   are joined.  An ROI item's blocks come back in order and are scattered
+   into a zeroed canvas exactly like the oracle (the same advanced-index
+   write, unselected blocks zero).
+
+``decode_fused_op`` is the stream decode the program runs.  Where it is
+replaced (a decode at another precision, as ``bench/control.py`` does),
+it runs on the same stream and the copy back follows as its own step.
 """
 from __future__ import annotations
+
+import contextlib
+import threading
 
 import jax
 import numpy as np
 
-from repro.kernels.decode.ops import MIN_COLUMNS, decode_fused_op, pad_bucket
+from repro.kernels.decode import ops
+from repro.kernels.decode.ops import (MIN_COLUMNS, column_table,
+                                      copy_back_op, decode_canvas_op,
+                                      decode_fused_op, pad_bucket)
 from repro.utils import trace
 
 #: max abs difference from the numpy oracle on 0-255 pixels (module doc)
@@ -46,67 +63,108 @@ ORACLE_ATOL = 1e-2
 DecodeItem = tuple
 
 
-def _gather_gops(seq, idx: list[int]) -> np.ndarray:
-    """Select GOP members from the ``kq``/``pq`` field, which is a stacked
-    ndarray for in-memory tiles or a per-GOP list for lazy npz reads."""
-    if isinstance(seq, np.ndarray):
-        return seq[idx]
-    return np.stack([seq[g] for g in idx])
-
-
 class _Slot:
     """Where one item's columns live inside its group's block stream."""
 
-    __slots__ = ("item", "n", "n_gops", "bsel", "offset", "span")
+    __slots__ = ("item", "n", "n_gops", "bsel", "nb", "offset", "span")
 
-    def __init__(self, item, n, n_gops, bsel, offset, span):
+    def __init__(self, item, n, n_gops, bsel, nb, offset, span):
         self.item = item
         self.n = n                  # frames decoded per selected GOP
         self.n_gops = n_gops
         self.bsel = bsel            # None = full tile
+        self.nb = nb                # blocks a GOP
         self.offset = offset
         self.span = span
 
+    def raster(self) -> tuple[int, int]:
+        """``(W8, H8)``: a frame's copy-back raster, in blocks."""
+        _, enc, _ = self.item
+        if self.bsel is None:
+            return enc["w"] // 8, enc["h"] // 8
+        return 1, self.nb
 
-def _gather(slots: list[_Slot], f_bucket: int, m_pad: int) -> np.ndarray:
-    """One group's ``[F, M, 8, 8]`` int16 block stream: each slot's
-    selected GOPs, ROI blocks only, in its column span."""
-    q = np.zeros((f_bucket, m_pad, 8, 8), dtype=np.int16)
+
+class _Scratch:
+    """The host buffer the stream is gathered into, kept between calls.
+
+    A fresh buffer of 100 MB faults its pages in as it is first written,
+    which costs more than the copy itself (PERF.md, "batched decode").  The
+    buffer is reused by one call at a time, from the gather until the
+    device has read it; a call that finds it taken allocates its own."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._buf = np.empty(0, dtype=np.int16)
+
+    @contextlib.contextmanager
+    def take(self, size: int):
+        if not self._lock.acquire(blocking=False):
+            yield np.empty(size, dtype=np.int16)
+            return
+        try:
+            if self._buf.size < size:
+                self._buf = np.empty(size, dtype=np.int16)
+            yield self._buf[:size]
+        finally:
+            self._lock.release()
+
+
+_SCRATCH = _Scratch()
+
+
+def _gather(slots: list[_Slot], q: np.ndarray) -> None:
+    """Fill ``q``, one group's ``[F, M, 64]`` int16 stream: each slot's
+    selected GOPs, ROI blocks only, in its column span; zeros elsewhere."""
+    end = slots[-1].offset + slots[-1].span
+    q[:, end:] = 0                              # the padding columns
     for s in slots:
         _, enc, idx = s.item
-        kq = _gather_gops(enc["kq"], idx)          # [G, nb, 8, 8]
-        if s.bsel is not None:
-            kq = kq[:, s.bsel]
-        q[0, s.offset:s.offset + s.span] = kq.reshape(-1, 8, 8)
-        if s.n > 1:
-            pq = _gather_gops(enc["pq"], idx)[:, :s.n - 1]
-            if s.bsel is not None:
-                pq = pq[:, :, s.bsel]
-            # [G, n-1, nb, 8, 8] -> [n-1, G*nb, 8, 8] gop-major columns
-            q[1:s.n, s.offset:s.offset + s.span] = \
-                pq.transpose(1, 0, 2, 3, 4).reshape(s.n - 1, s.span, 8, 8)
-    return q
+        cols = q[:, s.offset:s.offset + s.span].reshape(
+            q.shape[0], s.n_gops, s.nb, 64)
+        cols[s.n:] = 0                           # the padding frames
+        for j, g in enumerate(idx):
+            rows = [enc["kq"][g]] + list(enc["pq"][g][:s.n - 1])
+            for f, blocks in enumerate(rows):
+                blocks = blocks.reshape(-1, 64)
+                if s.bsel is None:
+                    cols[f, j] = blocks
+                else:
+                    np.take(blocks, s.bsel, axis=0, out=cols[f, j],
+                            mode="clip")
 
 
-def _scatter(s: _Slot, out: np.ndarray) -> np.ndarray:
-    """One slot's columns of the decoded stream as its output canvas."""
+def _canvas(s: _Slot, planes: np.ndarray) -> tuple[np.ndarray, bool]:
+    """One slot's output canvas from the copied-back planes (``ops``
+    module doc), and whether it is a view of them."""
     _, enc, _ = s.item
     h, w = enc["h"], enc["w"]
-    seg = out[:s.n, s.offset:s.offset + s.span]
+    start = s.offset * 64
+    gops = [planes[:s.n, start + g * s.nb * 64:start + (g + 1) * s.nb * 64]
+            for g in range(s.n_gops)]
     if s.bsel is None:
-        # [n, G, h/8, w/8, 8, 8] -> gop-major frames [G*n, h, w]
-        arr = seg.reshape(s.n, s.n_gops, h // 8, w // 8, 8, 8)
-        arr = arr.transpose(1, 0, 2, 4, 3, 5)
-        return np.ascontiguousarray(arr.reshape(s.n_gops * s.n, h, w))
+        frames = [seg.reshape(s.n, h, w) for seg in gops]
+        if len(frames) == 1:
+            return frames[0], True
+        return np.concatenate(frames), False
     canvas = np.zeros((s.n_gops * s.n, h, w), dtype=np.float32)
     view = canvas.reshape(-1, h // 8, 8, w // 8, 8)
     rs, cs = np.divmod(s.bsel, w // 8)
-    frames = seg.reshape(s.n, s.n_gops, -1, 8, 8)
-    frames = frames.transpose(1, 0, 2, 3, 4).reshape(
-        s.n_gops * s.n, -1, 8, 8)
-    # same advanced-index write as the oracle's ROI scatter
-    view[:, rs, :, cs] = frames.transpose(1, 0, 2, 3)
-    return canvas
+    for g, seg in enumerate(gops):
+        # same advanced-index write as the oracle's ROI scatter
+        view[g * s.n:(g + 1) * s.n, rs, :, cs] = \
+            seg.reshape(s.n, s.nb, 8, 8).transpose(1, 0, 2, 3)
+    return canvas, False
+
+
+def _dispatch(q, tab, *, qp, use_pallas, interpret):
+    """The flat stream -> the flat copied-back planes."""
+    if decode_fused_op is ops.decode_fused_op:
+        return decode_canvas_op(q, tab, qp=qp, use_pallas=use_pallas,
+                                interpret=interpret)
+    out = decode_fused_op(q.reshape(-1, tab.size, 8, 8), qp=qp,
+                          use_pallas=use_pallas, interpret=interpret)
+    return copy_back_op(out, tab)
 
 
 def decode_tile_batch(items, *, use_pallas: bool | None = None,
@@ -116,7 +174,8 @@ def decode_tile_batch(items, *, use_pallas: bool | None = None,
     ``items``: sequence of ``(enc, gop_indices, frames_within, blocks)``
     tuples.  Returns one ``[T', h, w] float32`` array per item, within
     :data:`ORACLE_ATOL` of ``decode_tile(enc, gop_indices, frames_within,
-    blocks)``.
+    blocks)``.  A full tile's array of one GOP is a read-only view of its
+    dispatch's copied-back planes.
     """
     results: list = [None] * len(items)
     # (qp, F_bucket) -> next free column / that group's slots
@@ -129,36 +188,50 @@ def decode_tile_batch(items, *, use_pallas: bool | None = None,
         idx = (list(range(n_gops_total)) if gop_indices is None
                else list(gop_indices))
         n = gop if frames_within is None else max(1, min(frames_within, gop))
+        nb_all = (h // 8) * (w // 8)
+        bsel = None
         if blocks is not None:
             bsel = np.asarray(sorted(set(blocks)), dtype=np.intp)
-            nb_sel = int(bsel.size)
-        else:
-            bsel = None
-            nb_sel = (h // 8) * (w // 8)
-        if not idx or nb_sel == 0:
+            if bsel.size and not 0 <= bsel[0] <= bsel[-1] < nb_all:
+                raise IndexError(f"block mask outside the {nb_all} blocks "
+                                 "of the tile")
+            if bsel.size == nb_all:
+                bsel = None                    # a mask of every block
+        nb = nb_all if bsel is None else int(bsel.size)
+        if not idx or nb == 0:
             # nothing to dispatch: the oracle returns an all-zero canvas
             results[i] = np.zeros((len(idx) * n, h, w), dtype=np.float32)
             continue
         key = (qp, pad_bucket(n, lo=1))
         off = columns.get(key, 0)
-        span = len(idx) * nb_sel
+        span = len(idx) * nb
         columns[key] = off + span
         slots_by_group.setdefault(key, []).append(
-            _Slot((i, enc, idx), n, len(idx), bsel, off, span))
+            _Slot((i, enc, idx), n, len(idx), bsel, nb, off, span))
 
     for (qp, f_bucket), slots in slots_by_group.items():
-        total = columns[(qp, f_bucket)]
-        with trace.span("tasm.decode.gather"):
-            q = _gather(slots, f_bucket, pad_bucket(total, lo=MIN_COLUMNS))
-        with trace.span("tasm.decode.dispatch"):
-            out = decode_fused_op(q, qp=qp, use_pallas=use_pallas,
-                                  interpret=interpret)
-        with trace.span("tasm.decode.device"):
-            out = jax.block_until_ready(out)
+        m_pad = pad_bucket(columns[(qp, f_bucket)], lo=MIN_COLUMNS)
+        with _SCRATCH.take(f_bucket * m_pad * 64) as q:
+            with trace.span("tasm.decode.gather"):
+                _gather(slots, q.reshape(f_bucket, m_pad, 64))
+                tab = column_table(m_pad, [(s.offset, s.span, *s.raster())
+                                           for s in slots])
+            with trace.span("tasm.decode.dispatch"):
+                out = _dispatch(q, tab, qp=qp, use_pallas=use_pallas,
+                                interpret=interpret)
+            with trace.span("tasm.decode.device"):
+                out = jax.block_until_ready(out)
         with trace.span("tasm.decode.d2h"):
             out = np.asarray(out)
+        n_views = 0
         with trace.span("tasm.decode.scatter"):
+            planes = out.reshape(f_bucket, m_pad * 64)
             for s in slots:
                 i, _, _ = s.item
-                results[i] = _scatter(s, out)
+                results[i], is_view = _canvas(s, planes)
+                n_views += is_view
+        trace.count("tasm.decode.h2d_bytes", q.nbytes + tab.nbytes)
+        trace.count("tasm.decode.d2h_bytes", out.nbytes)
+        trace.count("tasm.decode.view_slots", n_views)
+        trace.count("tasm.decode.host_slots", len(slots) - n_views)
     return results

@@ -176,6 +176,14 @@ class WorkloadPredictor:
             self._hist.pop(video, None)
 
 
+def _pins_more(arr: np.ndarray) -> bool:
+    """Whether ``arr`` is a view that keeps a larger array alive."""
+    base = arr
+    while isinstance(base.base, np.ndarray):
+        base = base.base
+    return base.nbytes > arr.nbytes
+
+
 def _block_mask2d(blocks: frozenset, h: int, w: int) -> np.ndarray:
     """Boolean pixel mask for a set of tile-local row-major 8x8-block
     indices (the codec's block geometry; see ``codec/encode.py``)."""
@@ -332,6 +340,10 @@ class TileCache:
                 with self._lock:
                     self._prefetch_wasted += 1
             return False
+        if e.mask2d is None and _pins_more(e.arr):
+            # a view of a larger buffer (a batched decode's copy back):
+            # own its bytes, so that the budget counts what is held
+            e.arr = e.arr.copy()
         with self._lock:
             old = self._lru.pop(key, None)
             if old is not None:

@@ -247,6 +247,53 @@ class TestBlockPackedEntries:
         assert c.get(("v", 0, 0, 0), 4).base is not None
 
 
+# ================================================ views of a decode's buffer
+class _CopySpy(np.ndarray):
+    """An array that counts its own ``copy`` calls."""
+
+    copies = 0
+
+    def copy(self, *args, **kwargs):
+        type(self).copies += 1
+        return super().copy(*args, **kwargs)
+
+
+def _canvas_view(monkeypatch):
+    """A [4, 16, 16] canvas that views a buffer three times its size, as
+    a full tile's canvas views its dispatch's copied-back planes."""
+    monkeypatch.setattr(_CopySpy, "copies", 0)
+    buf = np.arange(3 * 4 * 16 * 16, dtype=np.float32).view(_CopySpy)
+    return buf, buf[4 * 16 * 16:2 * 4 * 16 * 16].reshape(4, 16, 16)
+
+
+class TestViewAdmission:
+    @pytest.mark.parametrize("blocks", [None, tuple(range(4))])
+    def test_admitted_view_is_an_owned_copy_charged_its_bytes(
+            self, monkeypatch, blocks):
+        buf, view = _canvas_view(monkeypatch)
+        c = TileCache(config=CacheConfig(budget_bytes=1 << 20))
+        assert c.put(("v", 0, 0, 0), view, blocks=blocks)
+        assert _CopySpy.copies == 1
+        got = c.get(("v", 0, 0, 0), blocks=blocks)
+        assert not np.shares_memory(got, buf)
+        np.testing.assert_array_equal(got, view)
+        assert c.stats().bytes_cached == view.nbytes
+
+    def test_owned_canvas_is_kept_without_a_copy(self, monkeypatch):
+        buf, _ = _canvas_view(monkeypatch)
+        c = TileCache(config=CacheConfig(budget_bytes=1 << 20))
+        assert c.put(("v", 0, 0, 0), buf.reshape(12, 16, 16))
+        assert _CopySpy.copies == 0
+        assert np.shares_memory(c.get(("v", 0, 0, 0)), buf)
+
+    def test_disabled_cache_makes_no_copy(self, monkeypatch):
+        _, view = _canvas_view(monkeypatch)
+        c = TileCache(config=CacheConfig(budget_bytes=0))
+        assert not c.put(("v", 0, 0, 0), view)
+        assert _CopySpy.copies == 0
+        assert len(c) == 0 and c.stats().bytes_cached == 0
+
+
 # ======================================================= expected-reuse evict
 class TestReuseEviction:
     def test_reused_entry_outlives_older_colder(self):

@@ -25,7 +25,8 @@ from repro.core import (CacheConfig, DecodeConfig, NoTilingPolicy,
                         RegretPolicy, VideoStore, uniform_layout)
 from repro.core.cost import CostModel
 from repro.core.storage import TileStore
-from repro.kernels.decode import MIN_COLUMNS, decode_fused_ref, pad_bucket
+from repro.kernels.decode import (MIN_COLUMNS, decode_fused_op,
+                                  decode_fused_ref, pad_bucket)
 
 ENC = EncoderConfig(gop=16, qp=8)
 MODEL = CostModel(beta=1.4e-8, gamma=1e-5)
@@ -354,3 +355,236 @@ class TestBatchedDeterminism:
             [merged.scan("cam0").labels(l).frames(*fr) for l, fr in QUERIES])
         for x, y in zip(rs, rm):
             assert_regions_equal(x.regions, y.regions)
+
+
+# ------------ the copy back in canvas order against the host relayout
+def _ref_gather_gops(seq, idx):
+    if isinstance(seq, np.ndarray):
+        return seq[idx]
+    return np.stack([seq[g] for g in idx])
+
+
+def _ref_gather(slots, f_bucket, m_pad):
+    """The ``[F, M, 8, 8]`` stream built into zeros by fancy-index copies,
+    as the host built it before: slots are ``(enc, idx, n, bsel, offset,
+    span)``."""
+    q = np.zeros((f_bucket, m_pad, 8, 8), dtype=np.int16)
+    for enc, idx, n, bsel, off, span in slots:
+        kq = _ref_gather_gops(enc["kq"], idx)
+        if bsel is not None:
+            kq = kq[:, bsel]
+        q[0, off:off + span] = kq.reshape(-1, 8, 8)
+        if n > 1:
+            pq = _ref_gather_gops(enc["pq"], idx)[:, :n - 1]
+            if bsel is not None:
+                pq = pq[:, :, bsel]
+            q[1:n, off:off + span] = \
+                pq.transpose(1, 0, 2, 3, 4).reshape(n - 1, span, 8, 8)
+    return q
+
+
+def _ref_scatter(slot, out):
+    """One slot's columns of the decoded stream as its canvas, on the
+    host, as before the device copied back in canvas order."""
+    enc, idx, n, bsel, off, span = slot
+    h, w, g = enc["h"], enc["w"], len(idx)
+    seg = out[:n, off:off + span]
+    if bsel is None:
+        arr = seg.reshape(n, g, h // 8, w // 8, 8, 8)
+        arr = arr.transpose(1, 0, 2, 4, 3, 5)
+        return np.ascontiguousarray(arr.reshape(g * n, h, w))
+    canvas = np.zeros((g * n, h, w), dtype=np.float32)
+    view = canvas.reshape(-1, h // 8, 8, w // 8, 8)
+    rs, cs = np.divmod(bsel, w // 8)
+    frames = seg.reshape(n, g, -1, 8, 8).transpose(1, 0, 2, 3, 4)
+    view[:, rs, :, cs] = frames.reshape(g * n, -1, 8, 8).transpose(1, 0, 2, 3)
+    return canvas
+
+
+def _ref_decode_tile_batch(items, use_pallas, interpret):
+    """``decode_tile_batch`` as it was: stream gathered on the host,
+    decoded by ``decode_fused_op``, canvases scattered on the host."""
+    results = [None] * len(items)
+    columns, groups = {}, {}
+    for i, (enc, gop_indices, frames_within, blocks) in enumerate(items):
+        h, w, gop, qp = enc["h"], enc["w"], enc["gop"], enc["qp"]
+        idx = (list(range(len(enc["kq"]))) if gop_indices is None
+               else list(gop_indices))
+        n = gop if frames_within is None else max(1, min(frames_within, gop))
+        bsel = (None if blocks is None
+                else np.asarray(sorted(set(blocks)), dtype=np.intp))
+        nb = (h // 8) * (w // 8) if bsel is None else bsel.size
+        if not idx or nb == 0:
+            results[i] = np.zeros((len(idx) * n, h, w), dtype=np.float32)
+            continue
+        key = (qp, pad_bucket(n, lo=1))
+        off = columns.get(key, 0)
+        columns[key] = off + len(idx) * nb
+        groups.setdefault(key, []).append(
+            (i, (enc, idx, n, bsel, off, len(idx) * nb)))
+    for (qp, f_bucket), slots in groups.items():
+        q = _ref_gather([s for _, s in slots], f_bucket,
+                        pad_bucket(columns[(qp, f_bucket)], lo=MIN_COLUMNS))
+        out = np.asarray(decode_fused_op(q, qp=qp, use_pallas=use_pallas,
+                                         interpret=interpret))
+        for i, s in slots:
+            results[i] = _ref_scatter(s, out)
+    return results
+
+
+def _layout_items(case):
+    """Selections of one kind: tiles of 1x1 to 3x4 blocks, GOP 8."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    enc = {(bh, bw, qp): _rand_enc(rng, bh * 8, bw * 8, 8, qp, 3)
+           for bh, bw in [(1, 1), (2, 3), (3, 2), (3, 4)] for qp in (8, 12)}
+    tiles = [enc[(bh, bw, 8)] for bh, bw in [(2, 3), (1, 1), (3, 4)]]
+    if case == "full_tiles":
+        return [(e, [1], None, None) for e in tiles]
+    if case == "roi_masks":
+        return [(tiles[0], [0], None, (0, 2, 5)),
+                (tiles[2], [2], None, (11, 3, 4, 7)),
+                (enc[(3, 2, 8)], [1], None, (1,))]
+    if case == "all_block_masks":
+        return [(e, [0], None, tuple(range((e["h"] // 8) * (e["w"] // 8))))
+                for e in tiles]
+    if case == "multi_gop":
+        return [(tiles[0], [0, 1, 2], None, None),
+                (tiles[2], [2, 0], None, (1, 6, 9)),
+                (tiles[1], None, None, None)]
+    if case == "tails":
+        return [(tiles[0], [2], 3, None), (tiles[2], [1], 5, (0, 4, 8)),
+                (enc[(3, 2, 8)], [0, 1], 1, None),
+                (tiles[1], [0], 8, (0,))]
+    if case == "two_qp_groups":
+        return [(tiles[0], [0], None, None),
+                (enc[(3, 4, 12)], [1], None, (2, 3)),
+                (enc[(2, 3, 12)], [0, 2], 6, None),
+                (tiles[2], [1], 2, (5,))]
+    assert case == "empty_selection"
+    return [(tiles[0], [], None, None), (tiles[2], [1], None, ()),
+            (tiles[1], [0], 4, None)]
+
+
+LAYOUT_CASES = ["full_tiles", "roi_masks", "all_block_masks", "multi_gop",
+                "tails", "two_qp_groups", "empty_selection"]
+BACKENDS = [pytest.param(False, id="jnp"),
+            pytest.param(True, id="pallas_interpret")]
+
+
+@pytest.mark.parametrize("use_pallas", BACKENDS)
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_canvases_bitwise_equal_to_host_relayout(case, use_pallas):
+    items = _layout_items(case)
+    got = decode_tile_batch(items, use_pallas=use_pallas,
+                            interpret=use_pallas)
+    want = _ref_decode_tile_batch(items, use_pallas, use_pallas)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+def test_empty_batch_dispatches_nothing():
+    assert decode_tile_batch([]) == []
+
+
+@pytest.mark.parametrize("case", ["tails", "multi_gop", "roi_masks"])
+def test_gather_into_a_used_buffer_builds_the_host_stream(case):
+    """The stream gathered into a reused (dirty) buffer is the one the
+    host relayout built into zeros: every other byte is zeroed."""
+    import repro.codec.batch as batch
+
+    by_depth = {}
+    for enc, idx, fw, blocks in _layout_items(case):
+        idx = list(range(len(enc["kq"]))) if idx is None else idx
+        n = enc["gop"] if fw is None else fw
+        bsel = None if blocks is None else np.asarray(blocks, np.intp)
+        by_depth.setdefault((enc["qp"], pad_bucket(n, lo=1)), []).append(
+            (enc, idx, n, bsel))
+    for (_, f_bucket), group in by_depth.items():
+        slots, ref, off = [], [], 0
+        for enc, idx, n, bsel in group:
+            nb = (enc["h"] // 8) * (enc["w"] // 8) if bsel is None \
+                else bsel.size
+            slots.append(batch._Slot((0, enc, idx), n, len(idx), bsel, nb,
+                                     off, len(idx) * nb))
+            ref.append((enc, idx, n, bsel, off, len(idx) * nb))
+            off += len(idx) * nb
+        m_pad = pad_bucket(off, lo=MIN_COLUMNS)
+        q = np.full((f_bucket, m_pad, 64), -7, dtype=np.int16)
+        batch._gather(slots, q)
+        np.testing.assert_array_equal(q.reshape(f_bucket, m_pad, 8, 8),
+                                      _ref_gather(ref, f_bucket, m_pad))
+
+
+def _warm_items(depth, m, encs):
+    """What the benchmark's ``warm_shapes`` decodes for one (depth, column
+    bucket): the first ``m`` blocks of the tiles, as block masks."""
+    items = []
+    for enc in encs:
+        nb = (enc["h"] // 8) * (enc["w"] // 8)
+        take = min(nb, m)
+        if take:
+            items.append((enc, [0], depth, tuple(range(take))))
+        m -= take
+    return items
+
+
+def test_mixed_full_and_roi_slots_reuse_the_warmed_programs():
+    from repro.kernels.decode import ops
+
+    rng = np.random.default_rng(11)
+    encs = [_rand_enc(rng, bh * 8, bw * 8, 8, 8, 2)
+            for bh, bw in [(4, 6), (6, 5), (5, 8), (3, 3)]]
+    total = sum((e["h"] // 8) * (e["w"] // 8) for e in encs)
+    depths, buckets = [1, 2, 4, 8], [64, total]
+    for depth in depths:
+        for m in buckets:
+            decode_tile_batch(_warm_items(depth, m, encs))
+    warmed = ops._decode_fused._cache_size()
+    # the same buckets, now with whole tiles (no mask) beside ROI masks
+    mixed = [(encs[0], [0], 8, None), (encs[1], [1], 8, (0, 7, 9)),
+             (encs[2], [0], 3, None), (encs[3], [1], 3, (1, 2)),
+             (encs[2], [1], 1, (4,)), (encs[1], [0], 2, None)]
+    for depth in depths:
+        mixed.append((encs[3], [0], depth, None))
+    everything = [(e, [0], 8, None) for e in encs]
+    got = [decode_tile_batch(items) for items in (mixed, everything)]
+    assert ops._decode_fused._cache_size() == warmed
+    for items, canvases in zip((mixed, everything), got):
+        want = _ref_decode_tile_batch(items, False, False)
+        for g, w in zip(canvases, want):
+            np.testing.assert_array_equal(g, w)
+
+
+def test_counters_once_per_dispatch_with_the_bytes_moved(monkeypatch):
+    import repro.codec.batch as batch
+    from repro.utils import trace
+
+    rec = trace.Recorder()
+    monkeypatch.setattr(trace, "count", rec.count)
+    moved = []
+    real = batch.decode_canvas_op
+
+    def spy(q, tab, **kw):
+        out = real(q, tab, **kw)
+        moved.append((q.nbytes + tab.nbytes, out.nbytes))
+        return out
+
+    monkeypatch.setattr(batch, "decode_canvas_op", spy)
+    items = _layout_items("two_qp_groups") + _layout_items("multi_gop")
+    decode_tile_batch(items)
+    got = {name: [r.value for r in rec.window(0.0, np.inf)
+                  if r.name == name]
+           for name in ("tasm.decode.h2d_bytes", "tasm.decode.d2h_bytes",
+                        "tasm.decode.view_slots", "tasm.decode.host_slots")}
+    assert len(moved) == 3          # (qp, depth bucket): (8, 8), (12, 8), (8, 2)
+    assert got["tasm.decode.h2d_bytes"] == [h for h, _ in moved]
+    assert got["tasm.decode.d2h_bytes"] == [d for _, d in moved]
+    slots = [v + hst for v, hst in zip(got["tasm.decode.view_slots"],
+                                       got["tasm.decode.host_slots"])]
+    assert len(slots) == 3 and sum(slots) == len(items)
+    # views: whole tiles of one GOP; joined GOPs and ROI masks are host
+    want_views = sum(1 for enc, idx, fw, blocks in items
+                     if blocks is None and idx is not None and len(idx) == 1)
+    assert sum(got["tasm.decode.view_slots"]) == want_views

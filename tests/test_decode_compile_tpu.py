@@ -2,7 +2,8 @@
 
 Each case lowers ``_decode_fused(..., use_pallas=True)`` — the jitted op
 ``codec/batch.py`` dispatches, with the column block the served code picks
-(``block_columns``) — for one chip of a described ``v5e:2x2`` topology, and
+(``block_columns``), on a stream alone and as served, with the copy back
+in canvas order — for one chip of a described ``v5e:2x2`` topology, and
 compiles it with the TPU compiler.  Nothing runs: these guard what the
 chip's compiler would refuse (VMEM overflow above all: a fixed 128-column
 block does not fit once a GOP is 16 frames deep).  F covers a keyframe
@@ -62,3 +63,20 @@ def test_decode_kernel_compiles_for_v5e(one_chip, f, m):
                                    interpret=False).compile()
     # the Mosaic kernel itself is in the program, not the jnp reference
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("f,m", [(1, 64), (32, 32768)])
+def test_decode_program_with_copy_back_compiles_for_v5e(one_chip, f, m):
+    """The program a served dispatch runs: the flat stream and the column
+    table in, the kernel, and the copy back in canvas order."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.decode.ops import _decode_fused
+
+    q = jax.ShapeDtypeStruct((f * m * 64,), jnp.int16, sharding=one_chip)
+    tab = jax.ShapeDtypeStruct((m,), jnp.uint32, sharding=one_chip)
+    compiled = _decode_fused.lower(q, tab, qp=8, use_pallas=True,
+                                   interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes == f * m * 64 * 4
