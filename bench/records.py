@@ -69,13 +69,14 @@ def idle_pct(ctx):
 
 def decode_roofline_pct(ctx):
     """The decoded pixels of the traced window at ``bytes_per_pixel`` each,
-    over peak HBM bandwidth, as a share of the decode program's device
-    time in the trace."""
+    over peak HBM bandwidth, as a share of the decode kernel's own device
+    time in the trace (``kernel_s``: not the copy back or the relayouts
+    that the decode program runs around it)."""
     import trace_reduce
 
     t = ctx.trace
-    if t is None or t["program_s"] <= 0 or ctx.pixels_in_window <= 0:
+    if t is None or t["kernel_s"] <= 0 or ctx.pixels_in_window <= 0:
         return None
     return trace_reduce.roofline_pct(ctx.pixels_in_window
                                      * ctx.bytes_per_pixel,
-                                     t["program_s"], ctx.device_kind)
+                                     t["kernel_s"], ctx.device_kind)

@@ -19,11 +19,12 @@ DATA = BENCH / "tests" / "data" / "v5e_trace.json"
 
 def _doc():
     # one device; ops [10,20] [15,30] [50,60] ns x 1000 inside window
-    # [0, 100]; the decode program ran [10,30] and [50,60]
+    # [0, 100]; the decode program's kernel (a custom call) ran [10,20]
     us = 1000
+    kernel = "%_decode_fused.1 = f32[32,8192,8,8]{3,2,1,0} custom-call(s16)"
     return {"window": [0, 100 * us],
             "device": {"/device:TPU:0": {
-                "XLA Ops": [["fusion", 10 * us, 10 * us],
+                "XLA Ops": [[kernel, 10 * us, 10 * us],
                             ["copy", 15 * us, 15 * us],
                             ["fusion", 50 * us, 10 * us]],
                 "XLA Modules": [["jit__decode_fused(1)", 10 * us, 20 * us],
@@ -38,9 +39,11 @@ def test_union_gaps_and_program_time():
     r = trace_reduce.reduce(_doc(), "_decode_fused")
     assert r["window_s"] == pytest.approx(100e-6)
     assert r["busy_s"] == pytest.approx(30e-6)          # [10,30] + [50,60]
-    assert r["program_s"] == pytest.approx(25e-6)
-    assert r["top_ops"] == [["fusion", pytest.approx(20e-6)],
-                            ["copy", pytest.approx(15e-6)]]
+    assert r["kernel_s"] == pytest.approx(10e-6)
+    assert r["top_ops"] == [["copy", pytest.approx(15e-6)],
+                            ["_decode_fused.1 f32[32,8192,8,8]",
+                             pytest.approx(10e-6)],
+                            ["fusion", pytest.approx(10e-6)]]
     # gaps: [60,100] (40), [30,50] (20), [0,10] (10), longest first
     assert [g[1] for g in r["gaps"]] == pytest.approx([40e-6, 20e-6,
                                                        10e-6])
@@ -92,8 +95,11 @@ def test_recorded_v5e_trace():
                if any(s <= a and b <= s + d for _, s, d in ops))
     assert r["busy_s"] == pytest.approx(busy / 1e9)
     assert 0 < r["busy_s"] < r["window_s"]
-    # the decode program's modules span its ops and the slivers between
-    assert 0.9 * r["busy_s"] < r["program_s"] < 1.1 * r["busy_s"]
+    # its kernel is the custom call, without the relayout copies around it
+    kernel = sum(max(0, min(s + d, hi) - max(s, lo)) for n, s, d in ops
+                 if " custom-call(" in n and n.startswith("%_decode_fused"))
+    assert r["kernel_s"] == pytest.approx(kernel / 1e9)
+    assert 0.5 * r["busy_s"] < r["kernel_s"] < r["busy_s"]
     assert all(" f32[" in n or " s16[" in n or " (" in n
                for n, _ in r["top_ops"])
     assert len(r["gaps"]) <= 10 and len(r["top_ops"]) <= 10

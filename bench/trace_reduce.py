@@ -6,9 +6,11 @@ window's bounds); ``reduce`` computes from that document:
 
 - ``busy_s``: the union of the intervals in which an op ran, per device
   plane, averaged over the planes; ``window_s``: the traced window;
-- ``program_s``: the summed device time of every run of a named program
-  (a jitted function), from the "XLA Modules" line -- the kernel and the
-  pads and relayouts around it;
+- ``kernel_s``: the summed device time of a named program's kernel alone,
+  without the pads, relayouts and copies the program runs around it: the
+  ops of the "XLA Ops" line that are a ``custom-call`` named after the
+  program (a Pallas kernel lowers to one, ``%_decode_fused.1 = f32[...]
+  custom-call(...)``);
 - ``top_ops``: device time by op name, longest first;
 - ``gaps``: the device's idle gaps, longest first, each labelled by the
   host event that overlaps it most.
@@ -101,6 +103,14 @@ def op_name(raw: str) -> str:
     return f"{name.lstrip('%')} {rhs.split('{')[0].split(' ')[0]}"
 
 
+def is_kernel(raw: str, program: str) -> bool:
+    """Whether an op's HLO text is the program's kernel: a ``custom-call``
+    whose name starts with the program's."""
+    name, sep, rhs = raw.partition(" = ")
+    return bool(sep) and name.lstrip("%").startswith(program) \
+        and " custom-call(" in rhs
+
+
 def _op_lines(lines: dict) -> list:
     if "XLA Ops" in lines:
         return lines["XLA Ops"]
@@ -121,7 +131,7 @@ def reduce(doc: dict, program: str, top: int = 10) -> dict | None:
     else:
         lo = min(s for ops in planes.values() for _, s, _ in ops)
         hi = max(s + d for ops in planes.values() for _, s, d in ops)
-    busy, by_op, gaps = 0, {}, []
+    busy, kernel_ns, by_op, gaps = 0, 0, {}, []
     for ops in planes.values():
         merged = _union(((s, s + d) for _, s, d in ops), lo, hi)
         busy += sum(e - s for s, e in merged)
@@ -133,15 +143,12 @@ def reduce(doc: dict, program: str, top: int = 10) -> dict | None:
             if clipped > 0:
                 key = op_name(name)
                 by_op[key] = by_op.get(key, 0) + clipped
-    program_ns = 0
-    for lines in doc["device"].values():
-        for name, s, d in lines.get("XLA Modules", []):
-            if program in name:
-                program_ns += max(0, min(s + d, hi) - max(s, lo))
+                if is_kernel(name, program):
+                    kernel_ns += clipped
     gaps.sort(key=lambda g: g[0] - g[1])
     return {"busy_s": busy / len(planes) / 1e9,
             "window_s": (hi - lo) / 1e9,
-            "program_s": program_ns / 1e9,
+            "kernel_s": kernel_ns / 1e9,
             "top_ops": [[n, t / 1e9] for n, t in sorted(
                 by_op.items(), key=lambda kv: -kv[1])[:top]],
             "gaps": [[_label(doc["host"], s, e), (e - s) / 1e9]
