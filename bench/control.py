@@ -40,16 +40,27 @@ import run
 PRECISIONS = ("f32", "high", "bf16")
 
 
-def requested_keys(spec: dict, cfg: dict, arch, seed: int, seconds: float):
-    """The region keys a run of this seed would check."""
+def requested_keys(spec: dict, cfg: dict, archives: dict, seed: int,
+                   seconds: float) -> dict:
+    """Camera -> the region keys a run of this seed would check: its
+    sampled replies and, in a cluster cell, its read-back from each
+    replica."""
     job, by_key = run.job_for(spec, cfg, seed, seconds)
     if job["loop"] == "open":
         qs = [by_key[json.dumps(i)] for i in job["sample"]]
     else:
         qs = [by_key[json.dumps([c, s])]
               for c, seqs in enumerate(job["sample"]) for s in seqs]
-    return sorted({k for q in qs for k in arch.regions(q["label"], q["lo"],
-                                                        q["hi"])})
+    if "cluster" in cfg:
+        import cluster
+
+        qs += cluster.read_back_queries(spec["mix"], cfg)
+    keys: dict = {}
+    for q in qs:
+        cam = q.get("camera", 0)
+        keys.setdefault(cam, set()).update(
+            archives[cam].regions(q["label"], q["lo"], q["hi"]))
+    return {cam: sorted(k) for cam, k in keys.items()}
 
 
 def _jnp_decode(precision):
@@ -130,6 +141,9 @@ def tpu_gaps(arch, keys) -> dict:
 def in_program(args, precision: str) -> int:
     """Whole runs of the cell with the decode at ``precision`` in the
     program's place; one line per seed."""
+    if "cluster" in run.load_cell(args.workload)["config"]:
+        raise SystemExit("a cluster cell's nodes are their own processes: "
+                         "plant the control with run.py --node-fault high")
     run.prepare_env(args.rehearse)
     import repro.codec.batch as batch
 
@@ -171,20 +185,29 @@ def main(argv=None) -> int:
     import reference
 
     for seed in (int(s) % (1 << 64) for s in args.seeds.split(",")):
-        frames, dets = corpus.generate(cfg, seed)
-        arch = reference.Archive(frames, dets, cfg["gop"], cfg["qp"])
-        keys = requested_keys(spec, cfg, arch, seed, args.seconds)
-        arch.prepare(keys)
+        if "cluster" in cfg:
+            made = {c: corpus.generate(cfg, [seed, c])
+                    for c in range(cfg["cluster"]["cameras"])}
+        else:
+            made = {0: corpus.generate(cfg, seed)}
+        archives = {c: reference.Archive(frames, dets, cfg["gop"], cfg["qp"])
+                    for c, (frames, dets) in made.items()}
+        keys = requested_keys(spec, cfg, archives, seed, args.seconds)
         gaps = {p: 0.0 for p in PRECISIONS}
-        for f, box in keys:
-            ref = arch.pixels(f, box)
-            for p in PRECISIONS:
-                if ref.size:
-                    gaps[p] = max(gaps[p], float(np.max(np.abs(
-                        arch.pixels(f, box, p) - ref))))
-        gaps.update(tpu_gaps(arch, keys))
+        for cam, ks in keys.items():
+            arch = archives[cam]
+            arch.prepare(ks)
+            for f, box in ks:
+                ref = arch.pixels(f, box)
+                for p in PRECISIONS:
+                    if ref.size:
+                        gaps[p] = max(gaps[p], float(np.max(np.abs(
+                            arch.pixels(f, box, p) - ref))))
+            for p, g in tpu_gaps(arch, ks).items():
+                gaps[p] = max(gaps.get(p, 0.0), g)
         print(json.dumps({"workload": args.workload, "seed": seed,
-                          "regions": len(keys), "gaps": gaps,
+                          "regions": sum(map(len, keys.values())),
+                          "gaps": gaps,
                           "limit": cfg["check"]["pixel_gap_limit"]}),
               flush=True)
     return 0

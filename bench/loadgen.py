@@ -8,6 +8,8 @@ stdout one JSON line --
 a record per request -- followed by the raw float32 pixels of the sampled
 answers, whose shapes the JSON line gives.
 
+A request with a ``camera`` asks that camera's video (``job["videos"]``);
+any other asks the cell's one video (``job["video"]``).
 Open loop: one thread sends each request when it is due and never waits
 for replies; a reply's completion time is taken when the client has it.
 Closed loop: one thread per client, each with its own connection.
@@ -61,8 +63,13 @@ def _finish(rec: dict, fut, sampled: bool, col: Collector) -> None:
         col.keep(rec, res)
 
 
+def _video(job: dict, q: dict) -> str:
+    """The video a request asks: its camera's, else the cell's one video."""
+    return job["videos"][q["camera"]] if "camera" in q else job["video"]
+
+
 def run_open(job: dict, store, col: Collector) -> list[dict]:
-    t0, video = job["t0"], job["video"]
+    t0 = job["t0"]
     sample = set(job["sample"])
     recs, futs = [], []
     for r in job["requests"]:
@@ -71,7 +78,7 @@ def run_open(job: dict, store, col: Collector) -> list[dict]:
         if delay > 0:
             time.sleep(delay)
         rec["sent"] = time.monotonic()
-        fut = store.scan(video).labels(r["label"]).frames(
+        fut = store.scan(_video(job, r)).labels(r["label"]).frames(
             r["lo"], r["hi"]).submit()
         fut.add_done_callback(
             lambda f, rec=rec, s=r["i"] in sample: _finish(rec, f, s, col))
@@ -87,7 +94,7 @@ def run_open(job: dict, store, col: Collector) -> list[dict]:
 
 
 def run_closed(job: dict, stores: list, col: Collector) -> list[dict]:
-    t0, video = job["t0"], job["video"]
+    t0 = job["t0"]
     end, deadline = t0 + job["seconds"], t0 + job["seconds"] + job["grace"]
     recs: list[dict] = []
     lock = threading.Lock()
@@ -103,7 +110,7 @@ def run_closed(job: dict, stores: list, col: Collector) -> list[dict]:
             rec = dict(q, key=[c, seq], due=now - t0, sent=now)
             with lock:
                 recs.append(rec)
-            fut = store.scan(video).labels(q["label"]).frames(
+            fut = store.scan(_video(job, q)).labels(q["label"]).frames(
                 q["lo"], q["hi"]).submit()
             try:
                 fut.result(timeout=max(0.0, deadline - now))

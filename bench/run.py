@@ -24,6 +24,10 @@ records a profiler trace of the window.  After the window it reads the
 device's peak memory, stops the server, and checks a sample of the answers
 against ``reference.py``.
 
+A configuration with a ``cluster`` block is served instead by node
+processes, one chip each, behind the program's router in this process,
+which then holds no chip (``cluster.py``).
+
 Stdout ends with one JSON line: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer ones), ``device``, with ``--trace 1`` ``breakdown``, and last
@@ -37,6 +41,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -64,6 +69,8 @@ GRACE_S = 60.0
 DEPTH_BUCKETS = (1, 2, 4, 8, 16, 32)
 #: the jitted decode program, as its device trace names it
 DECODE_PROGRAM = "_decode_fused"
+#: faults node.py can plant in a cluster node (--fault)
+NODE_FAULTS = ("start", "corrupt_sot", "altered", "high")
 #: bytes a decoded pixel moves at least: an int16 coefficient in, a
 #: float32 pixel out
 BYTES_PER_PIXEL = 6
@@ -117,6 +124,126 @@ def rehearsal_size(cfg: dict) -> dict:
             for o in cfg["objects"]]
     return dict(cfg, height=96, width=160, n_frames=2 * cfg["gop"],
                 objects=objs)
+
+
+def detections(dets) -> list:
+    """The corpus's detections as the program takes them."""
+    return [[(lab, tuple(int(v) for v in box)) for lab, box in d]
+            for d in dets]
+
+
+def encoding(cfg: dict, dets: list) -> dict:
+    """The ingest keywords of an archive with these detections: encoder,
+    tiling policy and, for KQKO, the layouts it chooses at ingest from the
+    detections alone, so that each tile is encoded once from the source
+    frames."""
+    from repro.codec.encode import EncoderConfig
+    from repro.core import KQKOPolicy, NoTilingPolicy, SemanticIndex
+
+    enc = EncoderConfig(gop=cfg["gop"], qp=cfg["qp"])
+    lay = cfg["layout"]
+    if lay["policy"] != "kqko":
+        return {"detections": dets, "initial_layouts": None, "encoder": enc,
+                "policy": NoTilingPolicy()}
+    policy = KQKOPolicy(lay["query_objects"])
+    index = SemanticIndex()
+    for f, d in enumerate(dets):
+        for lab, box in d:
+            index.add(VIDEO, f, lab, box)
+    gop, n = cfg["gop"], cfg["n_frames"]
+    sots = [types.SimpleNamespace(sot_id=s, frame_start=s * gop,
+                                  frame_end=(s + 1) * gop)
+            for s in range(n // gop)]
+    shim = types.SimpleNamespace(sots=sots, encoder=enc)
+    layouts = policy.on_ingest(index, shim, VIDEO,
+                               (cfg["height"], cfg["width"]))
+    return {"detections": dets, "initial_layouts": layouts, "encoder": enc,
+            "policy": policy}
+
+
+def warm_shapes(ts, cfg: dict, mix: dict) -> int:
+    """Decode once at every (depth bucket, column bucket) that the mix's
+    requests can hand the batched decode of the tile store ``ts``: each
+    call is one stream of exactly that shape.  Whole-GOP scans decode every
+    block of a SOT at the GOP's depth, one shape; selections start
+    anywhere, so they reach every depth up to their longest range and,
+    merged, any column count up to a SOT's.  Returns the number of
+    shapes."""
+    rec = ts.sots[0]
+    cells = [(t, b) for t in range(rec.layout.n_tiles)
+             for b in range(rec.layout.tile_blocks(t))]
+    gop = cfg["gop"]
+    if mix["queries"]["kind"] == "gop_cycle":
+        depths, buckets = [gop], [len(cells)]
+    else:
+        top = min(traffic.longest(mix["queries"]), gop)
+        depths = [min(d, gop) for d in DEPTH_BUCKETS if d < 2 * top]
+        buckets, m = [], 64
+        while m // 2 < len(cells):
+            buckets.append(min(m, len(cells)))
+            m *= 2
+    n = 0
+    for depth in depths:
+        for m in buckets:
+            masks: dict = {}
+            for t, b in cells[:m]:
+                masks.setdefault(t, []).append(b)
+            ts.decode_tiles(rec.sot_id, sorted(masks), n_frames=depth,
+                            blocks={t: tuple(v) for t, v in masks.items()})
+            n += 1
+    return n
+
+
+# --------------------------------------------------------- load generator
+def spawn_loadgen(job: dict) -> subprocess.Popen:
+    """Start the load generator (``loadgen.py``, on the CPU) and hand it
+    ``job``, which names the address it connects to."""
+    client = subprocess.Popen(
+        [sys.executable, str(HERE / "loadgen.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE)
+    client.stdin.write((json.dumps(dict(job, src=str(SRC))) + "\n").encode())
+    client.stdin.flush()
+    return client
+
+
+def wait_ready(client: subprocess.Popen) -> None:
+    line = client.stdout.readline()
+    if line.strip() != b"ready":
+        raise RuntimeError(f"load generator did not start: {line!r}")
+
+
+def start_window(client: subprocess.Popen, t0: float) -> None:
+    client.stdin.write((json.dumps({"t0": t0}) + "\n").encode())
+    client.stdin.flush()
+
+
+def collect(client: subprocess.Popen) -> tuple[list, list]:
+    """The load generator's records and sampled answers."""
+    import numpy as np
+
+    out = client.stdout
+    head = json.loads(out.readline())
+    blob = out.read(head["blob_bytes"])
+    rc = client.wait(timeout=60)
+    if rc != 0 or len(blob) != head["blob_bytes"]:
+        raise RuntimeError(f"load generator exited {rc}")
+    samples, off = [], 0
+    for meta in head["samples"]:
+        regions = []
+        for f, box, shape in meta["regions"]:
+            n = int(np.prod(shape)) * 4
+            px = np.frombuffer(blob, np.float32, n // 4, off)
+            regions.append((f, tuple(box), px.reshape(shape)))
+            off += n
+        samples.append((meta["key"], regions))
+    return head["records"], samples
+
+
+def stop_process(proc) -> None:
+    if proc is not None and proc.poll() is None:
+        proc.kill()
+        proc.wait(timeout=30)
 
 
 # ----------------------------------------------------------------- server
@@ -173,88 +300,21 @@ class Harness:
 
     def spawn_client(self, job: dict) -> None:
         """Start the load generator; it connects while set-up goes on."""
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        self.client = subprocess.Popen(
-            [sys.executable, str(HERE / "loadgen.py")], env=env,
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE)
-        self.client.stdin.write((json.dumps(dict(
-            job, addr=self.addr, video=VIDEO, src=str(SRC),
+        self.client = spawn_loadgen(dict(
+            job, addr=self.addr, video=VIDEO,
             max_frame_bytes=self.cfg["serving"]["max_frame_mb"] << 20))
-            + "\n").encode())
-        self.client.stdin.flush()
 
     def ingest(self) -> tuple:
         """Make the archive from the seed and ingest it; (frames, dets)."""
         import corpus
-        from repro.codec.encode import EncoderConfig
-        from repro.core import KQKOPolicy, NoTilingPolicy
 
-        cfg = self.cfg
-        frames, dets = corpus.generate(cfg, self.seed)
-        enc = EncoderConfig(gop=cfg["gop"], qp=cfg["qp"])
-        lay = cfg["layout"]
-        dets_t = [[(lab, tuple(int(v) for v in box)) for lab, box in d]
-                  for d in dets]
-        if lay["policy"] == "kqko":
-            policy = KQKOPolicy(lay["query_objects"])
-            layouts = self._kqko_layouts(policy, dets_t, enc)
-        else:
-            policy, layouts = NoTilingPolicy(), None
-        self.store.ingest(VIDEO, frames, detections=dets_t,
-                          initial_layouts=layouts, encoder=enc,
-                          policy=policy)
+        frames, dets = corpus.generate(self.cfg, self.seed)
+        dets_t = detections(dets)
+        self.store.ingest(VIDEO, frames, **encoding(self.cfg, dets_t))
         return frames, dets_t
 
-    def _kqko_layouts(self, policy, dets, enc) -> dict:
-        """The layouts KQKO chooses at ingest, from the detections alone, so
-        that each tile is encoded once from the source frames."""
-        from repro.core import SemanticIndex
-
-        index = SemanticIndex()
-        for f, d in enumerate(dets):
-            for lab, box in d:
-                index.add(VIDEO, f, lab, box)
-        gop, n = self.cfg["gop"], self.cfg["n_frames"]
-        sots = [types.SimpleNamespace(sot_id=s, frame_start=s * gop,
-                                      frame_end=(s + 1) * gop)
-                for s in range(n // gop)]
-        shim = types.SimpleNamespace(sots=sots, encoder=enc)
-        return policy.on_ingest(index, shim, VIDEO,
-                                (self.cfg["height"], self.cfg["width"]))
-
     def warm_shapes(self, mix: dict) -> int:
-        """Decode once at every (depth bucket, column bucket) that the
-        mix's requests can hand the batched decode: each call is one stream
-        of exactly that shape.  Whole-GOP scans decode every block of a SOT
-        at the GOP's depth, one shape; selections start anywhere, so they
-        reach every depth up to their longest range and, merged, any
-        column count up to a SOT's.  Returns the number of shapes."""
-        ts = self.store.video(VIDEO).store
-        rec = ts.sots[0]
-        cells = [(t, b) for t in range(rec.layout.n_tiles)
-                 for b in range(rec.layout.tile_blocks(t))]
-        gop = self.cfg["gop"]
-        if mix["queries"]["kind"] == "gop_cycle":
-            depths, buckets = [gop], [len(cells)]
-        else:
-            top = min(traffic.longest(mix["queries"]), gop)
-            depths = [min(d, gop) for d in DEPTH_BUCKETS
-                      if d < 2 * top]
-            buckets, m = [], 64
-            while m // 2 < len(cells):
-                buckets.append(min(m, len(cells)))
-                m *= 2
-        n = 0
-        for depth in depths:
-            for m in buckets:
-                masks: dict = {}
-                for t, b in cells[:m]:
-                    masks.setdefault(t, []).append(b)
-                ts.decode_tiles(rec.sot_id, sorted(masks), n_frames=depth,
-                                blocks={t: tuple(v) for t, v in
-                                        masks.items()})
-                n += 1
-        return n
+        return warm_shapes(self.store.video(VIDEO).store, self.cfg, mix)
 
     def warm_fill(self, queries: list) -> None:
         """Ask each query once, least popular first, so the most popular
@@ -265,9 +325,7 @@ class Harness:
                 q["lo"], q["hi"]).execute()
 
     def wait_ready(self) -> None:
-        line = self.client.stdout.readline()
-        if line.strip() != b"ready":
-            raise RuntimeError(f"load generator did not start: {line!r}")
+        wait_ready(self.client)
 
     # -- window -----------------------------------------------------------
     def window(self, seconds: float, trace_dir: str | None) -> dict:
@@ -278,8 +336,7 @@ class Harness:
         if trace_dir is not None:
             jax.profiler.start_trace(trace_dir, profiler_options=_quiet())
         t0 = time.monotonic() + 0.2
-        self.client.stdin.write((json.dumps({"t0": t0}) + "\n").encode())
-        self.client.stdin.flush()
+        start_window(self.client, t0)
         time.sleep(max(0.0, t0 - time.monotonic()))
         self._counting = True
         with jax.profiler.TraceAnnotation("bench.window"):
@@ -291,25 +348,7 @@ class Harness:
         return {"t0": t0, "pixels_in_window": pixels_in_window}
 
     def collect(self) -> tuple[list, list]:
-        """The load generator's records and sampled answers."""
-        import numpy as np
-
-        out = self.client.stdout
-        head = json.loads(out.readline())
-        blob = out.read(head["blob_bytes"])
-        rc = self.client.wait(timeout=60)
-        if rc != 0 or len(blob) != head["blob_bytes"]:
-            raise RuntimeError(f"load generator exited {rc}")
-        samples, off = [], 0
-        for meta in head["samples"]:
-            regions = []
-            for f, box, shape in meta["regions"]:
-                n = int(np.prod(shape)) * 4
-                px = np.frombuffer(blob, np.float32, n // 4, off)
-                regions.append((f, tuple(box), px.reshape(shape)))
-                off += n
-            samples.append((meta["key"], regions))
-        return head["records"], samples
+        return collect(self.client)
 
     def memory_peak(self) -> int:
         import jax
@@ -318,9 +357,7 @@ class Harness:
         return int(st.get("peak_bytes_in_use", 0))
 
     def close(self) -> None:
-        if self.client is not None and self.client.poll() is None:
-            self.client.kill()
-            self.client.wait(timeout=30)
+        stop_process(self.client)
         if self.server is not None:
             self.server.stop()
             self.server = None
@@ -339,40 +376,52 @@ def _quiet():
 
 
 # ------------------------------------------------------------------ check
-def check(samples, requests_by_key, frames, dets, cfg) -> dict:
-    """Compare each sampled answer with the reference: its region keys
-    exactly, its pixels by the widest gap (a block may break an encoder
-    rounding tie either way; see ``reference.encode_block_ties``)."""
-    import reference
-
-    arch = reference.Archive(frames, dets, cfg["gop"], cfg["qp"])
-    mismatched, keys = 0, []
+def compare(answers, archives: dict, limit: float, groups) -> dict:
+    """Compare answers with the reference: region keys exactly, pixels by
+    the widest gap (a block may break an encoder rounding tie either way;
+    see ``reference.encode_block_ties``).  ``answers``: ``(group, where,
+    camera, query, regions)``; ``archives``: camera -> ``reference.Archive``.
+    Returns, for each of ``groups``, its mismatched keys, widest gap,
+    regions and pixels compared, ties broken and where the widest gap
+    lies."""
+    out = {g: {"mismatched": 0, "gap": 0.0, "regions": 0, "pixels": 0,
+               "ties": 0, "worst": None} for g in groups}
+    keys: dict = {}
     pairs = []
-    for key, regions in samples:
-        q = requests_by_key[json.dumps(key)]
-        want = Counter(arch.regions(q["label"], q["lo"], q["hi"]))
+    for group, where, cam, q, regions in answers:
+        r = out[group]
+        want = Counter(archives[cam].regions(q["label"], q["lo"], q["hi"]))
         got = Counter((f, box) for f, box, _ in regions)
-        mismatched += sum(((want - got) + (got - want)).values())
+        r["mismatched"] += sum(((want - got) + (got - want)).values())
         wanted = set(want)
         for f, box, px in regions:
             if (f, box) in wanted:
-                keys.append((f, box))
-                pairs.append((key, f, box, px))
-    arch.prepare(keys)
-    limit = cfg["check"]["pixel_gap_limit"]
-    gap, n_px, ties, worst = 0.0, 0, 0, None
-    for key, f, box, px in pairs:
+                keys.setdefault(cam, []).append((f, box))
+                pairs.append((r, where, cam, f, box, px))
+    for cam, k in keys.items():
+        archives[cam].prepare(k)
+    for r, where, cam, f, box, px in pairs:
+        r["regions"] += 1
         if px.shape != (box[2] - box[0], box[3] - box[1]):
-            mismatched += 1
+            r["mismatched"] += 1
             continue
-        g, t = arch.gap(f, box, px, limit)
-        ties += t
-        n_px += px.size
-        if g > gap:
-            gap = g
-            worst = {"request": key, "frame": f, "box": list(box)}
-    return {"mismatched": mismatched, "gap": gap, "regions": len(pairs),
-            "pixels": n_px, "ties": ties, "worst": worst}
+        g, t = archives[cam].gap(f, box, px, limit)
+        r["ties"] += t
+        r["pixels"] += px.size
+        if g > r["gap"]:
+            r["gap"] = g
+            r["worst"] = dict(where, frame=f, box=list(box))
+    return out
+
+
+def sampled_answers(samples, requests_by_key) -> list:
+    """The load generator's sampled replies as ``compare`` answers."""
+    out = []
+    for key, regions in samples:
+        q = requests_by_key[json.dumps(key)]
+        out.append(("sample", {"request": key}, q.get("camera", 0), q,
+                    regions))
+    return out
 
 
 # -------------------------------------------------------------------- run
@@ -395,22 +444,21 @@ def job_for(spec: dict, cfg: dict, seed: int, seconds: float) -> tuple:
     return dict(job, seconds=seconds, grace=GRACE_S), by_key
 
 
-def prepare_env(rehearse: bool) -> None:
+def prepare_env(rehearse: bool, holds_chip: bool = True) -> None:
     """Before JAX starts: the compile cache at a fixed path in the checkout
-    (unless JAX_COMPILATION_CACHE_DIR names one), every program cached."""
+    (unless JAX_COMPILATION_CACHE_DIR names one), every program cached; a
+    process that holds no chip, or a rehearsal, on the CPU."""
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           str(HERE / ".jax_cache"))
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-    if rehearse:
+    if rehearse or not holds_chip:
         os.environ["JAX_PLATFORMS"] = "cpu"
     sys.path.insert(0, str(SRC))
 
 
-def run(args) -> int:
-    spec = load_cell(args.workload)
-    seed = args.seed % (1 << 64)
-    prepare_env(args.rehearse)
-
+def measure(args, spec: dict, seed: int) -> dict:
+    """One run of a one-chip cell: set-up, window, check.  Returns what
+    ``report`` reads."""
     h = Harness(spec, seed, args.rehearse)
     trace_dir = tempfile.mkdtemp(prefix="tbtrace") if args.trace else None
     try:
@@ -430,7 +478,7 @@ def run(args) -> int:
         mix = spec["mix"]
         if mix.get("warm_fill"):
             t = time.perf_counter()
-            cat = traffic.catalogue(mix["queries"], cfg["n_frames"], seed)
+            cat = traffic.catalogue(mix["queries"], cfg, seed)
             h.warm_fill(cat[:mix["warm_fill"]])
             log(f"warm fill: {mix['warm_fill']} catalogue queries in "
                 f"{time.perf_counter() - t:.3f} s")
@@ -442,23 +490,19 @@ def run(args) -> int:
         device["memory_peak_bytes"] = h.memory_peak()
         h.close()           # the program's state is freed before the check
         t = time.perf_counter()
-        result = check(samples, by_key, frames, dets, cfg)
+        import reference
+
+        arch = reference.Archive(frames, dets, cfg["gop"], cfg["qp"])
+        result = compare(sampled_answers(samples, by_key), {0: arch},
+                         cfg["check"]["pixel_gap_limit"], ["sample"])
+        result = result["sample"]
         log(f"check: {result['regions']} regions, {result['pixels']} pixels "
             f"against the reference in {time.perf_counter() - t:.3f} s; "
             f"{result['ties']} blocks broke an encoder rounding tie the "
             f"other way; widest gap at {json.dumps(result['worst'])}")
-    except NoChip as e:
-        print(f"run.py: {e}", file=sys.stderr, flush=True)
-        return 3
     finally:
         h.close()
-
-    ctx = types.SimpleNamespace(
-        records=records, t0=win["t0"], seconds=args.seconds,
-        grace=GRACE_S, setup_s=setup_s, device_kind=device["kind"],
-        pixels_in_window=win["pixels_in_window"],
-        bytes_per_pixel=BYTES_PER_PIXEL, trace=None)
-    breakdown = None
+    trace = None
     if trace_dir is not None:
         import trace_reduce
 
@@ -466,12 +510,29 @@ def run(args) -> int:
         shutil.rmtree(trace_dir, ignore_errors=True)
         if args.keep_trace:
             pathlib.Path(args.keep_trace).write_text(json.dumps(doc))
-        ctx.trace = trace_reduce.reduce(doc, DECODE_PROGRAM)
-        if ctx.trace is not None:
-            device["busy_s"] = ctx.trace["busy_s"]
-            device["window_s"] = ctx.trace["window_s"]
-            breakdown = {"device_ops": ctx.trace["top_ops"],
-                         "idle_gaps": ctx.trace["gaps"]}
+        trace = trace_reduce.reduce(doc, DECODE_PROGRAM)
+    return {"cfg": cfg, "records": records, "samples": samples,
+            "result": result, "t0": win["t0"], "setup_s": setup_s,
+            "device": device, "compiles": h.compiles, "trace": trace,
+            "ctx": {"pixels_in_window": win["pixels_in_window"]},
+            "checks": {}}
+
+
+def report(args, spec: dict, out: dict) -> None:
+    """The cell's metrics, checks and result line, from what ``measure``
+    (or the cluster's) returned."""
+    cfg, records, result = out["cfg"], out["records"], out["result"]
+    device, trace, compiles = out["device"], out["trace"], out["compiles"]
+    ctx = types.SimpleNamespace(
+        records=records, t0=out["t0"], seconds=args.seconds,
+        grace=GRACE_S, setup_s=out["setup_s"], device_kind=device["kind"],
+        bytes_per_pixel=BYTES_PER_PIXEL, trace=trace, **out["ctx"])
+    breakdown = None
+    if trace is not None:
+        device["busy_s"] = trace["busy_s"]
+        device["window_s"] = trace["window_s"]
+        breakdown = {"device_ops": trace["top_ops"],
+                     "idle_gaps": trace["gaps"]}
     metrics = {}
     for m in spec["per_layer" if args.trace else "end_to_end"]:
         value = reader(m["name"])(ctx)
@@ -485,12 +546,14 @@ def run(args) -> int:
               "region_key_mismatches": {"value": result["mismatched"],
                                         "limit": 0},
               "requests_never_answered": {"value": lost, "limit": 0},
-              "compiles_in_window": {"value": h.compiles, "limit": 0}}
-    correct = (result["gap"] <= limit and result["mismatched"] == 0
-               and lost == 0 and h.compiles == 0 and result["regions"] > 0)
-    sampled = {json.dumps(key) for key, _ in samples}
+              "compiles_in_window": {"value": compiles, "limit": 0},
+              **out["checks"]}
+    correct = (all(c["value"] <= c["limit"] for c in checks.values())
+               and result["regions"] > 0)
+    mix = spec["mix"]
+    sampled = {json.dumps(key) for key, _ in out["samples"]}
     done = [r for r in records if "stats" in r]
-    late = sorted(r["sent"] - (win["t0"] + r["due"]) for r in records)
+    late = sorted(r["sent"] - (out["t0"] + r["due"]) for r in records)
     log(f"offered: {len(records)} requests in {args.seconds} s "
         f"({len(records) / args.seconds:.3f}/s), {mix['arrivals']}")
     log(f"answered {len(done)}, failed {failed} (never answered {lost}); "
@@ -498,7 +561,7 @@ def run(args) -> int:
     if late:
         log(f"generator lateness: median {late[len(late) // 2] * 1e3:.3f} "
             f"ms, max {late[-1] * 1e3:.3f} ms")
-    log(f"compiles_in_window {h.compiles}")
+    log(f"compiles_in_window {compiles}")
     # a hit is a tile from the tile cache or from another request's decode
     # in the same merged batch
     served = Counter("+".join(k for k in ("hits", "misses")
@@ -515,6 +578,28 @@ def run(args) -> int:
         line["breakdown"] = breakdown
     line["checks"] = checks
     print(json.dumps(line), flush=True)
+
+
+def run(args) -> int:
+    spec = load_cell(args.workload)
+    seed = args.seed % (1 << 64)
+    if "cluster" in spec["config"]:
+        import cluster
+
+        # this process serves the router and holds no chip: each node
+        # process holds one
+        prepare_env(args.rehearse, holds_chip=False)
+        go = functools.partial(cluster.measure, this=sys.modules[__name__],
+                               node_fault=args.node_fault)
+    else:
+        prepare_env(args.rehearse)
+        go = measure
+    try:
+        out = go(args, spec, seed)
+    except NoChip as e:
+        print(f"run.py: {e}", file=sys.stderr, flush=True)
+        return 3
+    report(args, spec, out)
     return 0
 
 
@@ -529,6 +614,9 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--keep-trace", metavar="PATH",
                     help="with --trace 1, also write the compact trace "
                          "document there")
+    ap.add_argument("--node-fault", choices=NODE_FAULTS,
+                    help="plant a fault in the first node of a cluster "
+                         "cell (see node.py; for the tests)")
     return ap.parse_args(argv)
 
 

@@ -30,3 +30,14 @@ def mean(ctx, name: str, scale: float = 1.0):
 
 def mean_ms(ctx, name: str):
     return mean(ctx, name, 1e3)
+
+
+def nodes_mean_ms(ctx, name: str):
+    """Mean milliseconds of the spans called ``name`` over every node of a
+    cluster cell (``ctx.node_spans``: each node's window records as
+    ``[name, value]``), or ``None`` where a node dropped records of the
+    window or none ran."""
+    if any(recs is None for recs in ctx.node_spans):
+        return None
+    vals = [v for recs in ctx.node_spans for n, v in recs if n == name]
+    return 1e3 * sum(vals) / len(vals) if vals else None

@@ -39,8 +39,8 @@ def main(argv=None) -> int:
         h.warm_shapes(spec["mix"])
         if spec["mix"].get("warm_fill"):
             import traffic
-            h.warm_fill(traffic.catalogue(spec["mix"]["queries"],
-                                          h.cfg["n_frames"], args.seed)
+            h.warm_fill(traffic.catalogue(spec["mix"]["queries"], h.cfg,
+                                          args.seed)
                         [:spec["mix"]["warm_fill"]])
         for rate in (float(r) for r in args.rates.split(",")):
             mix = json.loads(json.dumps(spec["mix"]))

@@ -6,7 +6,9 @@ and plants one fault a cell can have: an answer altered where it is
 produced (the batched decode), half of the regions of each answer left
 out, and the control -- the decode at ``Precision.HIGH``, one step below
 the ``HIGHEST`` the configurations state, in the program's place -- which
-the harness's own comparison, tie search included, has to fail.
+the harness's own comparison, tie search included, has to fail.  In a
+cluster cell the decode runs in node processes, so the fault is planted in
+the first node through ``run.py --node-fault``.
 """
 from __future__ import annotations
 
@@ -27,12 +29,16 @@ CELLS = [w["name"] for w in json.loads(
     (BENCH.parent / "BENCHMARK.json").read_text())["workloads"]]
 
 
-def _line(capsys, monkeypatch, tmp_path, cell: str) -> dict:
+def _cluster(cell: str) -> bool:
+    return "cluster" in run.load_cell(cell)["config"]
+
+
+def _line(capsys, monkeypatch, tmp_path, cell: str, *extra) -> dict:
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     rc = run.run(run.parse_args(["--workload", cell, "--seed", "21",
                                  "--seconds", "2", "--trace", "0",
-                                 "--rehearse"]))
+                                 "--rehearse", *extra]))
     assert rc == 0
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
@@ -46,8 +52,12 @@ def test_altered_decode_is_caught(cell, capsys, monkeypatch, tmp_path):
     def altered(items, **kw):
         return [a + np.float32(0.01) for a in real(items, **kw)]
 
-    monkeypatch.setattr(batch, "decode_tile_batch", altered)
-    line = _line(capsys, monkeypatch, tmp_path, cell)
+    if _cluster(cell):
+        line = _line(capsys, monkeypatch, tmp_path, cell,
+                     "--node-fault", "altered")
+    else:
+        monkeypatch.setattr(batch, "decode_tile_batch", altered)
+        line = _line(capsys, monkeypatch, tmp_path, cell)
     assert line["correct"] is False
     c = line["checks"]["pixel_gap"]
     assert c["value"] > c["limit"]
@@ -58,8 +68,13 @@ def test_lower_precision_decode_is_caught(cell, capsys, monkeypatch,
                                          tmp_path):
     import repro.codec.batch as batch
 
-    monkeypatch.setattr(batch, "decode_fused_op", control.decode_op("high"))
-    line = _line(capsys, monkeypatch, tmp_path, cell)
+    if _cluster(cell):
+        line = _line(capsys, monkeypatch, tmp_path, cell,
+                     "--node-fault", "high")
+    else:
+        monkeypatch.setattr(batch, "decode_fused_op",
+                            control.decode_op("high"))
+        line = _line(capsys, monkeypatch, tmp_path, cell)
     assert line["correct"] is False
     c = line["checks"]["pixel_gap"]
     assert c["value"] > c["limit"]

@@ -36,6 +36,10 @@ REQUESTS = {
     ("mot16-select-zipf", 7, False): "d403bb0fbd55a9ea1f0bc203f2b4c5d231a9672f5f8d43d96b5ab994d7018257",
     ("mot16-select-zipf", 2147483659, True): "1ca10ebb624fe162f808499f5b6477abf346dcc4bedef64d784489d9bbe063d8",
     ("mot16-select-zipf", 2147483659, False): "9053baf44cc94ab65837e69c14420d7289e53d95754c3a5d78ae257387e35ac4",
+    ("vr2k-k2x4-select", 7, True): "469c7d34e88e7112c159802fd892e01c3cb5cd135e729fcb5f18dba92873ac0d",
+    ("vr2k-k2x4-select", 7, False): "ba86a696160b274b55511ff8ea29db75a71f0724233e58cffb1ce35403bf681c",
+    ("vr2k-k2x4-select", 2147483659, True): "dc51c472fa11633ee3bd2bd568ca0c50b1ebd4bd7b52738b1b69df49b6ab9606",
+    ("vr2k-k2x4-select", 2147483659, False): "65bbf359f74d2a99cb8e21ecb5944e87313f7335339120817072d2b602b71dc4",
 }
 
 
@@ -68,3 +72,28 @@ def test_one_camera_archives_are_unchanged(key):
     h = hashlib.sha256(frames.tobytes())
     h.update(json.dumps(dets).encode())
     assert h.hexdigest() == ARCHIVES[key]
+
+
+#: sha256 of each camera's rehearsal-size archive in a cluster cell, made
+#: from ``[seed, camera]``: (config, seed, camera) -> digest
+CAMERA_ARCHIVES = {
+    ("visualroad-2k-k2x4", 7, 0): "51ac00c8d9b724f668c6f199b65b4fbf9161f8b43ed8d886f18bb2328864a5a5",
+    ("visualroad-2k-k2x4", 7, 1): "411daf1dab392f6a932d10584ac60ca67406e5d2f3e1ea47398dcb3256c4496f",
+    ("visualroad-2k-k2x4", 7, 2): "37fa703dd6567c0995c43b798bf9925c1089d48538cfdfb7fc464cf6eae78ef3",
+    ("visualroad-2k-k2x4", 7, 3): "f266a56611f39cefb8f35618eb0e17589267be51a8b5b5eedc61940e0c632224",
+    ("visualroad-2k-k2x4", 2147483659, 0): "50eb95d5352edf4f2b9462d7d84f10f89e668017491c0e2c78eace9290a8f059",
+    ("visualroad-2k-k2x4", 2147483659, 1): "00cc45f94a383861775be61817929f9c0004ee7194ae31f9f1c2c7e7a7601ba0",
+    ("visualroad-2k-k2x4", 2147483659, 2): "86feb261c281cc8552f53b071f9cb4feb5d378868c383151b9731608b29c0d4a",
+    ("visualroad-2k-k2x4", 2147483659, 3): "fa1bb6975e559c50c3d09cd3c221b12280c5aec6a86aa2efe052adc3cc04c5b0",
+}
+
+
+@pytest.mark.parametrize("key", sorted(CAMERA_ARCHIVES, key=str))
+def test_camera_archives_are_unchanged(key):
+    name, seed, camera = key
+    cfg = run.rehearsal_size(json.loads(
+        (BENCH / "configs" / f"{name}.json").read_text()))
+    frames, dets = corpus.generate(cfg, [seed, camera])
+    h = hashlib.sha256(frames.tobytes())
+    h.update(json.dumps(dets).encode())
+    assert h.hexdigest() == CAMERA_ARCHIVES[key]

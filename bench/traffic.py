@@ -15,7 +15,9 @@ A mix (``bench/traffic/<name>.json``) has three parts.
   "length_range": [a, b]}``  k (label, range) queries, asked with Zipf(s)
   popularity (in a closed loop, in an order whose every stretch holds the
   ranks in their Zipf shares: smooth weighted round robin, the clients
-  taking turns along it);
+  taking turns along it); served by a cluster configuration of c
+  ``cameras``, entry i asks camera ``i mod c`` (a request's ``camera``), so
+  a round robin over the cameras;
   ``{"kind": "gop_cycle", "label": tag}``  whole GOPs of the whole-frame
   tag, each client cycling over the archive from its own starting GOP.
   Every query has a ``class`` ("sel" or "scan") that metrics filter on.
@@ -99,11 +101,14 @@ def _ranges(q: dict, n: int, n_frames: int, rng) -> list[dict]:
     return out
 
 
-def catalogue(q: dict, n_frames: int, seed: int) -> list[dict]:
-    """The k (label, range) queries of a catalogue mix, most popular
-    first.  Which label and length each popularity rank has is fixed
-    (dealt by a constant stream, so every seed asks the same amount of
-    work); the seed draws where each range starts."""
+def catalogue(q: dict, cfg: dict, seed: int) -> list[dict]:
+    """The k (label, range) queries of a catalogue mix over the archive of
+    configuration ``cfg``, most popular first.  Which label and length
+    each popularity rank has is fixed (dealt by a constant stream, so every
+    seed asks the same amount of work); the seed draws where each range
+    starts.  Where ``cfg`` is a cluster of c cameras, entry i asks camera
+    i mod c."""
+    n_frames = cfg["n_frames"]
     fixed = np.random.default_rng(0)
     k = int(q["size"])
     a, b = q["length_range"]
@@ -111,10 +116,13 @@ def catalogue(q: dict, n_frames: int, seed: int) -> list[dict]:
         a, min(b, n_frames), k)).astype(int))
     labels = fixed.permutation(_exact_counts(q["labels"], k))
     rng = np.random.default_rng([seed, 1])
+    cameras = cfg.get("cluster", {}).get("cameras", 0)
     out = []
-    for label, length in zip(labels, lengths):
+    for i, (label, length) in enumerate(zip(labels, lengths)):
         lo = int(rng.integers(0, n_frames - length + 1))
         out.append({"label": str(label), "lo": lo, "hi": lo + int(length)})
+        if cameras:
+            out[-1]["camera"] = i % cameras
     return out
 
 
@@ -139,7 +147,7 @@ def open_loop(mix: dict, cfg: dict, seed: int, seconds: float) -> list[dict]:
     if q["kind"] == "ranges":
         asks = _ranges(q, n, cfg["n_frames"], rng)
     elif q["kind"] == "catalogue":
-        cat = catalogue(q, cfg["n_frames"], seed)
+        cat = catalogue(q, cfg, seed)
         counts = _zipf_counts(len(cat), float(q["zipf_s"]), n)
         asks = [cat[i] for i in rng.permutation(np.repeat(
             np.arange(len(cat)), counts))]
@@ -170,7 +178,7 @@ def closed_loop(mix: dict, cfg: dict, seed: int, per_client: int
     q = mix["queries"]
     n_clients = int(mix["arrivals"]["clients"])
     if q["kind"] == "catalogue":
-        cat = catalogue(q, cfg["n_frames"], seed)
+        cat = catalogue(q, cfg, seed)
         order = _zipf_order(len(cat), float(q["zipf_s"]),
                             per_client * n_clients)
         return [[dict(cat[i], cls=q["class"]) for i in order[c::n_clients]]
